@@ -15,6 +15,7 @@ from repro.core.attention import decode_attention_lamp
 from repro.core.policy import LampSite
 from repro.kernels import ops, ref
 from repro.kernels.paged_attention import decode_kv_bytes
+from repro.models.layers import paged_kv_view
 
 from .common import emit, timed
 
@@ -24,9 +25,9 @@ def paged_decode_micro(R: int = 8, H: int = 4, Hkv: int = 2, hd: int = 64,
     """Gather-vs-fused paged decode at R concurrent ragged sequences."""
     rng = np.random.default_rng(0)
     n_blocks = 1 + R * n_max
-    arena_k = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, hd)) * 1.5,
+    arena_k = jnp.asarray(rng.normal(size=(n_blocks, Hkv, bs, hd)) * 1.5,
                           jnp.float32)
-    arena_v = jnp.asarray(rng.normal(size=(n_blocks, bs, Hkv, hd)),
+    arena_v = jnp.asarray(rng.normal(size=(n_blocks, Hkv, bs, hd)),
                           jnp.float32)
     lengths = jnp.asarray(rng.integers(1, n_max * bs, size=R), jnp.int32)
     perm = rng.permutation(np.arange(1, n_blocks))
@@ -41,10 +42,8 @@ def paged_decode_micro(R: int = 8, H: int = 4, Hkv: int = 2, hd: int = 64,
 
     @jax.jit
     def gather_decode(q, ak, av, bt, lengths):
-        ks = ak[bt].reshape(R, -1, Hkv, hd)
-        vs = av[bt].reshape(R, -1, Hkv, hd)
-        kh = jnp.repeat(jnp.moveaxis(ks, 2, 1), H // Hkv, axis=1)
-        vh = jnp.repeat(jnp.moveaxis(vs, 2, 1), H // Hkv, axis=1)
+        kh = paged_kv_view(ak, bt, H // Hkv)
+        vh = paged_kv_view(av, bt, H // Hkv)
         out, aux = decode_attention_lamp(q, kh, vh, lengths, site,
                                          reduce=False)
         return out, aux.n_selected

@@ -41,6 +41,11 @@ from .mixed_matmul import dot_ps
 from .policy import LampSite
 
 _NEG = -1e30
+# Every product in this module is FP32 as LAMP defines it: the references,
+# the selective recompute, softmax @ V, and the PS(mu) look-ahead pass
+# (which rounds on its own). HIGHEST keeps a TPU from running an f32 dot as
+# one bfloat16 pass, the default there.
+_HI = jax.lax.Precision.HIGHEST
 
 
 class AttnAux(NamedTuple):
@@ -101,10 +106,10 @@ def attention_reference(q, k, v, *, causal: bool = True, scale: Optional[float] 
     """Uniform FP32 attention (paper's reference)."""
     q, k, v = (jnp.asarray(t, jnp.float32) for t in (q, k, v))
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    y = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k)
+    y = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k, precision=_HI)
     where = _causal_where(q.shape[2], k.shape[2], offset, window) if causal else None
     z = L.masked_softmax(y, where)
-    return jnp.einsum("bhqk,bhkd->bhqd", z, v)
+    return jnp.einsum("bhqk,bhkd->bhqd", z, v, precision=_HI)
 
 
 def attention_lamp(q, k, v, site: LampSite, *, causal: bool = True,
@@ -160,10 +165,10 @@ def attention_lamp(q, k, v, site: LampSite, *, causal: bool = True,
         if wb is not None:
             mask &= wb
 
-    y_exact = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k)
+    y_exact = jnp.einsum("bhqd,bhkd->bhqk", q * scale, k, precision=_HI)
     y = jnp.where(mask, y_exact, y_low)
     z = L.masked_softmax(y, wb)
-    out = jnp.einsum("bhqk,bhkd->bhqd", z, v)
+    out = jnp.einsum("bhqk,bhkd->bhqd", z, v, precision=_HI)
 
     if reduce:
         n_sel = jnp.sum(mask.astype(jnp.float32))
@@ -220,7 +225,7 @@ def chunked_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = 
     def body(carry, xs):
         m, l, acc = carry
         kc, vc, bi = xs
-        y = jnp.einsum("bhqd,bhkd->bhqk", qs, kc)
+        y = jnp.einsum("bhqd,bhkd->bhqk", qs, kc, precision=_HI)
         kj = bi * block + jnp.arange(block)[None, :]
         ok = kj < Tk
         if causal:
@@ -232,7 +237,8 @@ def chunked_attention(q, k, v, *, causal: bool = True, scale: Optional[float] = 
         p = jnp.exp(y - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l = l * corr + jnp.sum(p, axis=-1)
-        acc = acc * corr[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, vc)
+        acc = acc * corr[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, vc,
+                                                 precision=_HI)
         return (m_new, l, acc), None
 
     m0 = jnp.full((B, H, Tq), _NEG)
@@ -312,7 +318,7 @@ def chunked_attention_lamp(q, k, v, site: LampSite, *, causal: bool = True,
         single MXU pass and y_low = round(y_exact): ONE matmul, not two
         (EXPERIMENTS Sec Perf, hillclimb C)."""
         if cast_only:
-            y_exact = jnp.einsum("bhqd,bhkd->bhqk", qs, kc)
+            y_exact = jnp.einsum("bhqd,bhkd->bhqk", qs, kc, precision=_HI)
             from repro.core.numerics import round_to_mantissa
             y = round_to_mantissa(y_exact, site.mu)
         elif site.enabled:
@@ -320,7 +326,7 @@ def chunked_attention_lamp(q, k, v, site: LampSite, *, causal: bool = True,
             y = dot_ps(qs, ktc, site.mu, granularity=site.granularity)
             y_exact = None
         else:
-            y = jnp.einsum("bhqd,bhkd->bhqk", qs, kc)
+            y = jnp.einsum("bhqd,bhkd->bhqk", qs, kc, precision=_HI)
             y_exact = None
         kj = bi * block + jnp.arange(block)[None, :]
         ok = kj < Tk
@@ -354,7 +360,8 @@ def chunked_attention_lamp(q, k, v, site: LampSite, *, causal: bool = True,
                 thr = smax_exact
             sel = ok & (s > log_tau + thr[..., None])
             if y_exact is None:
-                y_exact = jnp.einsum("bhqd,bhkd->bhqk", qs, kc)
+                y_exact = jnp.einsum("bhqd,bhkd->bhqk", qs, kc,
+                                     precision=_HI)
             y = jnp.where(sel, y_exact, y)
             nsel = nsel + jnp.sum(sel)
         y = jnp.where(ok, y, _NEG)
@@ -363,7 +370,8 @@ def chunked_attention_lamp(q, k, v, site: LampSite, *, causal: bool = True,
         p = jnp.where(ok, p, 0.0)
         corr = jnp.exp(m - m_new)
         l = l * corr + jnp.sum(p, axis=-1)
-        acc = acc * corr[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, vc)
+        acc = acc * corr[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, vc,
+                                                 precision=_HI)
         return (m_new, l, acc, smax_run, nsel), None
 
     m0 = jnp.full((B, H, Tq), _NEG)
@@ -412,13 +420,14 @@ def decode_attention_lamp(q, k_cache, v_cache, length, site: LampSite,
         mask = _select(y_low, site, ok,
                        row_lengths=jnp.broadcast_to(length[:, None, None], (B, H, Tq)),
                        tau=tau)
-        y_exact = jnp.matmul(qs, kt)
+        y_exact = jnp.matmul(qs, kt, precision=_HI)
         y = jnp.where(mask, y_exact, y_low)
     else:
-        y = jnp.matmul(qs, kt)
+        y = jnp.matmul(qs, kt, precision=_HI)
         mask = jnp.zeros(y.shape, bool)
     z = L.masked_softmax(y, ok)
-    out = jnp.einsum("bhqk,bhkd->bhqd", z, jnp.asarray(v_cache, jnp.float32))
+    out = jnp.einsum("bhqk,bhkd->bhqd", z, jnp.asarray(v_cache, jnp.float32),
+                     precision=_HI)
     if reduce:
         nsel = jnp.sum(mask.astype(jnp.float32))
         n_valid = jnp.sum(ok.astype(jnp.float32)) * H

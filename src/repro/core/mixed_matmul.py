@@ -30,6 +30,11 @@ import jax.numpy as jnp
 
 from .numerics import round_to_mantissa, round_to_mantissa_stochastic
 
+# The simulation's products accumulate in true FP32 before its own PS(mu)
+# rounding, and the LAMP recompute is FP32: HIGHEST on every dot, or a TPU
+# runs an f32 dot as one bfloat16 pass.
+_HI = jax.lax.Precision.HIGHEST
+
 
 def _round(c: jnp.ndarray, mu: int, stochastic: bool, key) -> jnp.ndarray:
     if stochastic:
@@ -57,9 +62,9 @@ def dot_ps(a: jnp.ndarray, b: jnp.ndarray, mu: int, *, granularity: int = 1,
     if b.shape[-2] != K:
         raise ValueError(f"contraction mismatch: {a.shape} @ {b.shape}")
     if mu >= 23:
-        return jnp.matmul(a, b)
+        return jnp.matmul(a, b, precision=_HI)
     if granularity == 0 or granularity >= K:
-        return _round(jnp.matmul(a, b), mu, stochastic,
+        return _round(jnp.matmul(a, b, precision=_HI), mu, stochastic,
                       key if key is not None else jax.random.PRNGKey(0))
     g = int(granularity)
     steps = -(-K // g)  # ceil
@@ -82,7 +87,7 @@ def dot_ps(a: jnp.ndarray, b: jnp.ndarray, mu: int, *, granularity: int = 1,
 
     def body(c, xs):
         ac, bc, k = xs
-        c = _round(c + jnp.matmul(ac, bc), mu, stochastic, k)
+        c = _round(c + jnp.matmul(ac, bc, precision=_HI), mu, stochastic, k)
         return c, None
 
     out, _ = jax.lax.scan(body, init, (a_chunks, b_chunks, keys))
@@ -106,7 +111,8 @@ def matmul_lamp(a: jnp.ndarray, b: jnp.ndarray, mu: int,
     """
     if y_low is None:
         y_low = dot_ps(a, b, mu, granularity=granularity)
-    y_exact = jnp.matmul(jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32))
+    y_exact = jnp.matmul(jnp.asarray(a, jnp.float32),
+                         jnp.asarray(b, jnp.float32), precision=_HI)
     return jnp.where(mask, y_exact, y_low)
 
 

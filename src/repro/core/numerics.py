@@ -20,13 +20,15 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-_EXP_MASK = jnp.uint32(0x7F800000)
 _F32_MANT_BITS = 23
 
 
 def _is_nonfinite(bits: jnp.ndarray) -> jnp.ndarray:
-    """True where the FP32 bit pattern is Inf or NaN (exponent all ones)."""
-    return (bits & _EXP_MASK) == _EXP_MASK
+    """True where the FP32 bit pattern is Inf or NaN (exponent all ones).
+    The mask is built here, not at module level: a Pallas kernel body may
+    not capture an array constant."""
+    exp_mask = jnp.uint32(0x7F800000)
+    return (bits & exp_mask) == exp_mask
 
 
 @functools.partial(jax.jit, static_argnames=("mu",))

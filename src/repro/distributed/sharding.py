@@ -115,11 +115,8 @@ def shard_hint(x, *spec):
     no-ops when no mesh / axes absent so model code stays mesh-agnostic.
     Spec entries: "batch" -> ("pod","data") as available, or literal axis
     names, or None."""
-    try:
-        env = jax.sharding.get_abstract_mesh()
-        names = env.axis_names if env is not None else ()
-    except Exception:
-        names = ()
+    env = jax.sharding.get_abstract_mesh()
+    names = env.axis_names
     if not names:
         return x
     resolved = []

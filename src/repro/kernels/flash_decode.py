@@ -17,7 +17,7 @@ pair implements rule (9) exactly -- matching `ref.flash_decode_ref`.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +25,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.numerics import round_to_mantissa
+
+from .backend import resolve_interpret
 
 _NEG = -1e30
 
@@ -108,7 +110,8 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, smax_ref, o_ref, nsel_ref,
     "mu", "tau", "block_k", "k_subtile", "interpret"))
 def flash_decode(q, k_cache, v_cache, length, *, mu: int = 7, tau: float = 0.05,
                  block_k: int = 512, k_subtile: int = 32,
-                 interpret: bool = True) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                 interpret: Optional[bool] = None,
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """q (B,H,1,D) vs caches (B,H,S,D), length (B,) ->
     (out (B,H,1,D) f32, n_selected)."""
     B, H, _, D = q.shape
@@ -135,7 +138,7 @@ def flash_decode(q, k_cache, v_cache, length, *, mu: int = 7, tau: float = 0.05,
         out_specs=pl.BlockSpec((1, 1), lambda i, kk: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, lens)
 
     out, nsel = pl.pallas_call(
@@ -163,6 +166,6 @@ def flash_decode(q, k_cache, v_cache, length, *, mu: int = 7, tau: float = 0.05,
             pltpu.VMEM((), jnp.float32),
             pltpu.VMEM((), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf, lens, smax)
     return out.reshape(B, H, 1, D), jnp.sum(nsel)

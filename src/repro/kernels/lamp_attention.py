@@ -32,6 +32,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.numerics import round_to_mantissa
 
+from .backend import resolve_interpret
+
 _NEG = -1e30
 
 
@@ -106,7 +108,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, nsel_ref,
 def lamp_flash_attention(q, k, v, *, mu: int = 7, tau: float = 0.05,
                          causal: bool = True, block_q: int = 128,
                          block_k: int = 128, k_subtile: int = 32,
-                         interpret: bool = True,
+                         interpret: Optional[bool] = None,
                          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """q,k,v: (B, H, T, D) -> (out (B,H,T,D) f32, n_selected scalar f32)."""
     B, H, T, D = q.shape
@@ -148,6 +150,6 @@ def lamp_flash_attention(q, k, v, *, mu: int = 7, tau: float = 0.05,
             pltpu.VMEM((block_q,), jnp.float32),     # running smax
             pltpu.VMEM((), jnp.float32),             # selection count
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(B, H, T, D), jnp.sum(nsel)

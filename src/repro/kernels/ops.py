@@ -1,89 +1,22 @@
-"""Jit'd public wrappers for the Pallas kernels.
+"""Public entry points of the Pallas kernels.
 
-`interpret` defaults to True off-TPU (this container is CPU-only; interpret
-mode executes the kernel bodies in Python for correctness validation) and
-False on TPU, where the kernels compile to Mosaic. The REPRO_PALLAS_INTERPRET
-env var overrides the default in both directions ("1" forces interpret mode,
-"0" forces compiled); tests/conftest.py pins it to "1" so tier-1 tests always
-exercise the real kernel code paths on CPU instead of skipping them.
+Every kernel takes ``interpret=None`` by default and resolves it through
+``backend.resolve_interpret``: compiled Mosaic on a TPU, the Pallas
+interpreter elsewhere. An explicit ``interpret=`` overrides it per call
+(the compile tests pass ``interpret=False`` to compile for a described
+TPU from a CPU process).
 """
 
 from __future__ import annotations
 
-import os
+from .backend import resolve_interpret
+from .flash_decode import flash_decode
+from .lamp_attention import lamp_flash_attention
+from .paged_attention import (paged_decode_attention, paged_mixed_attention,
+                              paged_prefill_attention)
+from .ps_matmul import ps_matmul
+from .rmsnorm import rmsnorm
 
-import jax
-import jax.numpy as jnp
-
-from .flash_decode import flash_decode as _flash_decode
-from .lamp_attention import lamp_flash_attention as _lamp_flash_attention
-from .paged_attention import (
-    paged_decode_attention as _paged_decode_attention,
-    paged_mixed_attention as _paged_mixed_attention,
-    paged_prefill_attention as _paged_prefill_attention,
-)
-from .ps_matmul import ps_matmul as _ps_matmul
-from .rmsnorm import rmsnorm as _rmsnorm
-
-
-def _default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env:  # empty string == unset: fall through to the backend default
-        return env.lower() not in ("0", "false")
-    return jax.default_backend() != "tpu"
-
-
-def lamp_flash_attention(q, k, v, *, mu: int = 7, tau: float = 0.05,
-                         causal: bool = True, block_q: int = 128,
-                         block_k: int = 128, k_subtile: int = 32,
-                         interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _lamp_flash_attention(q, k, v, mu=mu, tau=tau, causal=causal,
-                                 block_q=block_q, block_k=block_k,
-                                 k_subtile=k_subtile, interpret=interpret)
-
-
-def flash_decode(q, k_cache, v_cache, length, *, mu: int = 7, tau: float = 0.05,
-                 block_k: int = 512, k_subtile: int = 32, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _flash_decode(q, k_cache, v_cache, length, mu=mu, tau=tau,
-                         block_k=block_k, k_subtile=k_subtile,
-                         interpret=interpret)
-
-
-def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths, site,
-                           *, tau=None, window=None, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
-                                   site, tau=tau, window=window,
-                                   interpret=interpret)
-
-
-def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts, site,
-                            *, tau=None, qlens=None, window=None,
-                            block_q=None, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
-                                    site, tau=tau, qlens=qlens, window=window,
-                                    block_q=block_q, interpret=interpret)
-
-
-def paged_mixed_attention(q, arena_k, arena_v, block_tables, starts, qlens,
-                          site, *, tau=None, window=None, block_q=None,
-                          interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _paged_mixed_attention(q, arena_k, arena_v, block_tables, starts,
-                                  qlens, site, tau=tau, window=window,
-                                  block_q=block_q, interpret=interpret)
-
-
-def ps_matmul(a, b, *, mu: int = 7, block_m: int = 128, block_n: int = 128,
-              block_k: int = 128, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _ps_matmul(a, b, mu=mu, block_m=block_m, block_n=block_n,
-                      block_k=block_k, interpret=interpret)
-
-
-def rmsnorm(x, w, *, eps: float = 1e-6, block_rows: int = 256, interpret=None):
-    interpret = _default_interpret() if interpret is None else interpret
-    return _rmsnorm(x, w, eps=eps, block_rows=block_rows, interpret=interpret)
+__all__ = ["resolve_interpret", "flash_decode", "lamp_flash_attention",
+           "paged_decode_attention", "paged_mixed_attention",
+           "paged_prefill_attention", "ps_matmul", "rmsnorm"]

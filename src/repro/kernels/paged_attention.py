@@ -2,8 +2,8 @@
 
 Block-index map
 ---------------
-The serving engine stores KV in a per-layer block arena of shape
-(n_blocks, block_size, Hkv, hd); sequence r owns the ordered blocks
+The serving engine stores KV in a per-layer, head-major block arena of
+shape (n_blocks, Hkv, block_size, hd); sequence r owns the ordered blocks
 ``block_tables[r]`` (0 = reserved null block used for padding). The gather
 reference path (models/layers.py) materializes each row's view with
 ``arena[block_tables]`` -- O(n_max * block_size) HBM traffic per row per
@@ -20,6 +20,19 @@ re-map to an already-resident live block, costing no fresh DMA) and the
 block's compute is guarded with ``pl.when`` -- fully-masked blocks are
 skipped, not summed as zeros. GQA is resolved in the index map as well
 (query head -> kv head), so K/V are never repeated in memory.
+
+Mosaic tiling: every BlockSpec's last two dimensions are whole or tile
+multiples. A KV block is (1, 1, block_size, hd) of the head-major arena,
+indexed (block, kv_head, 0, 0); per-row statistics and selection counts
+carry a trailing unit axis ((rows, W, 1) blocked (1, wq, 1)), and the
+running softmax state lives in (wq, 1) VMEM scratch.
+
+Precision: every product here names Precision.HIGHEST. The selective
+recompute and the softmax @ V product are FP32 as LAMP defines them, and
+the PS(mu) look-ahead pass accumulates in true FP32 before its own
+rounding -- the same precision ``core.mixed_matmul.dot_ps`` and
+``core.attention`` use on the gather path, so the two paths agree on a TPU
+as they do on the CPU (a TPU's default f32 dot is one bfloat16 pass).
 
 LAMP two-pass layout
 --------------------
@@ -75,8 +88,11 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.numerics import round_to_mantissa
 from repro.core.policy import LampSite
 
+from .backend import resolve_interpret
+
 _NEG = -1e30
 _TINY = 1.1754944e-38  # float32 tiny: masked_softmax's normalizer clamp
+_HI = jax.lax.Precision.HIGHEST
 
 
 def supports_site(site: LampSite) -> bool:
@@ -87,23 +103,30 @@ def supports_site(site: LampSite) -> bool:
                                                "relaxed_ln")
 
 
+def _qk(q, k):
+    """FP32 q @ k^T: (rows, hd) x (keys, hd) -> (rows, keys)."""
+    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=_HI,
+                               preferred_element_type=jnp.float32)
+
+
+def _pv(p, v):
+    """FP32 softmax-weights @ V: (rows, keys) x (keys, hd) -> (rows, hd)."""
+    return jax.lax.dot(p, v, precision=_HI, preferred_element_type=jnp.float32)
+
+
 def _y_low(q, k, mu: int, granularity: int):
     """PS(mu) q @ k^T, bitwise-matching ``dot_ps``: granularity 0
     (cast-only) = one FP32 pass + final round; g >= 1 = FP32 accumulation
     inside K-chunks of g lanes, re-rounding the running accumulator."""
-    dn = (((1,), (1,)), ((), ()))
     if mu >= 23:
-        return jax.lax.dot_general(q, k, dn, preferred_element_type=jnp.float32)
+        return _qk(q, k)
     D = q.shape[-1]
     if granularity == 0 or granularity >= D:
-        y = jax.lax.dot_general(q, k, dn, preferred_element_type=jnp.float32)
-        return round_to_mantissa(y, mu)
+        return round_to_mantissa(_qk(q, k), mu)
     g = int(granularity)
     acc = jnp.zeros((q.shape[0], k.shape[0]), jnp.float32)
     for s in range(-(-D // g)):
-        part = jax.lax.dot_general(q[:, s * g:(s + 1) * g],
-                                   k[:, s * g:(s + 1) * g], dn,
-                                   preferred_element_type=jnp.float32)
+        part = _qk(q[:, s * g:(s + 1) * g], k[:, s * g:(s + 1) * g])
         acc = round_to_mantissa(acc + part, mu)
     return acc
 
@@ -132,6 +155,54 @@ def _select(y_low, ok, smax, m_low, l_low, n_row, *, rule: str, tau,
     raise ValueError(f"unsupported LAMP rule {rule!r}")
 
 
+def _rowmax(x):
+    return jnp.max(x, axis=-1, keepdims=True)
+
+
+def _rowsum(x):
+    return jnp.sum(x, axis=-1, keepdims=True)
+
+
+def _stats_block(y, ok, smax_ref, m_ref, l_ref):
+    """Pass-1 accumulation of one logits tile into the (rows, 1) running
+    row statistics: smax = max(y + log|y|), m = max y, l = sum exp(y - m)."""
+    s = jnp.where(ok, y + jnp.log(jnp.abs(y)), _NEG)
+    smax_ref[...] = jnp.maximum(smax_ref[...], _rowmax(s))
+    m_new = jnp.maximum(m_ref[...], _rowmax(jnp.where(ok, y, _NEG)))
+    p = jnp.where(ok, jnp.exp(y - m_new), 0.0)
+    l_ref[...] = l_ref[...] * jnp.exp(m_ref[...] - m_new) + _rowsum(p)
+    m_ref[...] = m_new
+
+
+def _softmax_block(y, ok, v, acc_ref, m_ref, l_ref):
+    """Pass-2 online-softmax accumulation of one tile into (rows, hd) acc
+    and (rows, 1) m / l."""
+    y = jnp.where(ok, y, _NEG)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, _rowmax(y))
+    p = jnp.where(ok, jnp.exp(y - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_ref[...] * corr + _rowsum(p)
+    acc_ref[...] = acc_ref[...] * corr + _pv(p, v)
+    m_ref[...] = m_new
+
+
+def _lamp_logits(q, k, ok, smax, m_low, l_low, n_row, cnt_ref, *, lamp, mu,
+                 granularity, rule, tau, n_ref):
+    """Pass-2 logits of one tile: the plain FP32 product with LAMP off;
+    otherwise the recomputed PS(mu) logits with the look-ahead selection
+    replaced by the FP32 product, counting selections per row."""
+    if not lamp:
+        return _qk(q, k)
+    y_low = _y_low(q, k, mu, granularity)
+    sel = _select(y_low, ok, smax, m_low, l_low, n_row, rule=rule, tau=tau,
+                  n_ref=n_ref)
+    cnt_ref[...] += _rowsum(sel.astype(jnp.float32))
+    if rule == "none":
+        return y_low
+    return jnp.where(sel, _qk(q, k), y_low)
+
+
 # ---------------------------------------------------------------------------
 # Decode variant: one query row per sequence, grid (R*H, n_max)
 # ---------------------------------------------------------------------------
@@ -148,7 +219,7 @@ def _dec_mask(j, L, bs, window):
     return live, ok
 
 
-def _dec_stats_kernel(bt_ref, len_ref, q_ref, k_ref, stats_ref,
+def _dec_stats_kernel(bt_ref, len_ref, q_ref, k_ref, smax_o, m_o, l_o,
                       smax_ref, m_ref, l_ref,
                       *, H, bs, n_k, mu, granularity, scale, window):
     i, j = pl.program_id(0), pl.program_id(1)
@@ -159,30 +230,24 @@ def _dec_stats_kernel(bt_ref, len_ref, q_ref, k_ref, stats_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    L = len_ref[i // H]
-    live, ok = _dec_mask(j, L, bs, window)
+    live, ok = _dec_mask(j, len_ref[i // H], bs, window)
 
     @pl.when(live)
     def _block():
         q = q_ref[0].astype(jnp.float32) * scale       # (1, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
-        y = _y_low(q, k, mu, granularity)              # (1, bs)
-        s = jnp.where(ok, y + jnp.log(jnp.abs(y)), _NEG)
-        smax_ref[...] = jnp.maximum(smax_ref[...], jnp.max(s))
-        m_new = jnp.maximum(m_ref[...], jnp.max(jnp.where(ok, y, _NEG)))
-        p = jnp.where(ok, jnp.exp(y - m_new), 0.0)
-        l_ref[...] = l_ref[...] * jnp.exp(m_ref[...] - m_new) + jnp.sum(p)
-        m_ref[...] = m_new
+        k = k_ref[0, 0].astype(jnp.float32)            # (bs, hd)
+        _stats_block(_y_low(q, k, mu, granularity), ok, smax_ref, m_ref, l_ref)
 
     @pl.when(j == n_k - 1)
     def _finish():
-        stats_ref[0, 0] = smax_ref[...]
-        stats_ref[0, 1] = m_ref[...]
-        stats_ref[0, 2] = l_ref[...]
+        smax_o[0] = smax_ref[...]
+        m_o[0] = m_ref[...]
+        l_o[0] = l_ref[...]
 
 
-def _dec_kernel(bt_ref, len_ref, tau_ref, q_ref, k_ref, v_ref, stats_ref,
-                o_ref, nsel_ref, acc_ref, m_ref, l_ref, cnt_ref,
+def _dec_kernel(bt_ref, len_ref, tau_ref, q_ref, k_ref, v_ref,
+                smax_in, mlow_in, llow_in, o_ref, nsel_ref,
+                acc_ref, m_ref, l_ref, cnt_ref,
                 *, H, bs, n_k, lamp, mu, granularity, rule, n_ref_ln,
                 scale, window):
     i, j = pl.program_id(0), pl.program_id(1)
@@ -200,50 +265,29 @@ def _dec_kernel(bt_ref, len_ref, tau_ref, q_ref, k_ref, v_ref, stats_ref,
     @pl.when(live)
     def _block():
         q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0].astype(jnp.float32)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if lamp:
-            y_low = _y_low(q, k, mu, granularity)
-            sel = _select(y_low, ok, stats_ref[0, 0], stats_ref[0, 1],
-                          stats_ref[0, 2], L, rule=rule, tau=tau_ref[0],
-                          n_ref=n_ref_ln)
-            if rule == "none":
-                y = y_low
-            else:
-                y_exact = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                y = jnp.where(sel, y_exact, y_low)
-            cnt_ref[...] += jnp.sum(sel.astype(jnp.float32))
-        else:
-            y = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        y = jnp.where(ok, y, _NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(y))
-        p = jnp.where(ok, jnp.exp(y - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p)
-        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+        y = _lamp_logits(q, k, ok, smax_in[0], mlow_in[0], llow_in[0], L,
+                         cnt_ref, lamp=lamp, mu=mu, granularity=granularity,
+                         rule=rule, tau=tau_ref[0], n_ref=n_ref_ln)
+        _softmax_block(y, ok, v, acc_ref, m_ref, l_ref)
 
     @pl.when(j == n_k - 1)
     def _finish():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], _TINY)
                     ).astype(o_ref.dtype)
-        nsel_ref[0, 0] = cnt_ref[...]
+        nsel_ref[0] = cnt_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("site", "window", "interpret"))
 def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
                            site: LampSite, *, tau=None,
                            window: Optional[int] = None,
-                           interpret: bool = True,
+                           interpret: Optional[bool] = None,
                            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One decode step straight off the paged arena (no pre-gather).
 
-    q: (R, H, 1, hd); arena_k/v: (n_blocks, block_size, Hkv, hd);
+    q: (R, H, 1, hd); arena_k/v: (n_blocks, Hkv, block_size, hd);
     block_tables: (R, n_max) int32; lengths: (R,) *effective* lengths (the
     new token's KV already written, so valid positions are [0, lengths[r])).
     Returns (out (R, H, 1, hd) float32, n_selected (R,) float32 summed over
@@ -252,15 +296,17 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
     `tau` (optional *traced* scalar) overrides the static ``site.tau``: it
     rides into the selection kernel as a third scalar-prefetch operand, so
     the policy controller can move the threshold every step without the jit
-    cache key (site is static) ever changing.
+    cache key (site is static) ever changing. `interpret=None` follows the
+    backend (``backend.resolve_interpret``).
     """
     R, H, Tq, hd = q.shape
     if Tq != 1:
         raise ValueError(f"decode takes one query row, got Tq={Tq}")
-    _, bs, Hkv, _ = arena_k.shape
+    _, Hkv, bs, _ = arena_k.shape
     n_max = block_tables.shape[1]
     rep = H // Hkv
     scale = hd ** -0.5
+    interpret = resolve_interpret(interpret)
     qf = q.reshape(R * H, 1, hd)
     bt = block_tables.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
@@ -276,11 +322,12 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
         L = len_ref[r]
         hi = (L - 1) // bs
         lo = 0 if window is None else jnp.maximum(L - window, 0) // bs
-        return (bt_ref[r, jnp.clip(j, lo, hi)], 0, (i % H) // rep, 0)
+        return (bt_ref[r, jnp.clip(j, lo, hi)], (i % H) // rep, 0, 0)
 
     q_spec = pl.BlockSpec((1, 1, hd), lambda i, j, *_: (i, 0, 0))
-    kv_spec = pl.BlockSpec((1, bs, 1, hd), kv_map)
-    stats_spec = pl.BlockSpec((1, 3), lambda i, j, *_: (i, 0))
+    kv_spec = pl.BlockSpec((1, 1, bs, hd), kv_map)
+    row_spec = pl.BlockSpec((1, 1, 1), lambda i, j, *_: (i, 0, 0))
+    row_shape = jax.ShapeDtypeStruct((R * H, 1, 1), jnp.float32)
 
     if need_stats:
         stats = pl.pallas_call(
@@ -291,14 +338,14 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
                 num_scalar_prefetch=2,
                 grid=(R * H, n_max),
                 in_specs=[q_spec, kv_spec],
-                out_specs=stats_spec,
-                scratch_shapes=[pltpu.VMEM((), jnp.float32)] * 3,
+                out_specs=[row_spec] * 3,
+                scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)] * 3,
             ),
-            out_shape=jax.ShapeDtypeStruct((R * H, 3), jnp.float32),
+            out_shape=[row_shape] * 3,
             interpret=interpret,
         )(bt, lens, qf, arena_k)
     else:
-        stats = jnp.zeros((R * H, 3), jnp.float32)
+        stats = [jnp.zeros((R * H, 1, 1), jnp.float32)] * 3
 
     out, nsel = pl.pallas_call(
         functools.partial(_dec_kernel, H=H, bs=bs, n_k=n_max, lamp=lamp,
@@ -308,24 +355,21 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(R * H, n_max),
-            in_specs=[q_spec, kv_spec, kv_spec, stats_spec],
-            out_specs=[
-                pl.BlockSpec((1, 1, hd), lambda i, j, *_: (i, 0, 0)),
-                pl.BlockSpec((1, 1), lambda i, j, *_: (i, 0)),
-            ],
+            in_specs=[q_spec, kv_spec, kv_spec] + [row_spec] * 3,
+            out_specs=[q_spec, row_spec],
             scratch_shapes=[
                 pltpu.VMEM((1, hd), jnp.float32),   # acc
-                pltpu.VMEM((), jnp.float32),        # m
-                pltpu.VMEM((), jnp.float32),        # l
-                pltpu.VMEM((), jnp.float32),        # nsel count
+                pltpu.VMEM((1, 1), jnp.float32),    # m
+                pltpu.VMEM((1, 1), jnp.float32),    # l
+                pltpu.VMEM((1, 1), jnp.float32),    # nsel count
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((R * H, 1, hd), jnp.float32),
-            jax.ShapeDtypeStruct((R * H, 1), jnp.float32),
+            row_shape,
         ],
         interpret=interpret,
-    )(bt, lens, tau_arr, qf, arena_k, arena_v, stats)
+    )(bt, lens, tau_arr, qf, arena_k, arena_v, *stats)
     return (out.reshape(R, H, 1, hd),
             jnp.sum(nsel.reshape(R, H), axis=1))
 
@@ -373,16 +417,8 @@ def _pre_stats_kernel(bt_ref, starts_ref, ql_ref, q_ref, k_ref,
     @pl.when(live)
     def _block():
         q = q_ref[0].astype(jnp.float32) * scale       # (wq, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)         # (bs, hd)
-        y = _y_low(q, k, mu, granularity)              # (wq, bs)
-        s = jnp.where(ok, y + jnp.log(jnp.abs(y)), _NEG)
-        smax_ref[...] = jnp.maximum(smax_ref[...], jnp.max(s, axis=-1))
-        m_new = jnp.maximum(m_ref[...],
-                            jnp.max(jnp.where(ok, y, _NEG), axis=-1))
-        p = jnp.where(ok, jnp.exp(y - m_new[:, None]), 0.0)
-        l_ref[...] = (l_ref[...] * jnp.exp(m_ref[...] - m_new)
-                      + jnp.sum(p, axis=-1))
-        m_ref[...] = m_new
+        k = k_ref[0, 0].astype(jnp.float32)            # (bs, hd)
+        _stats_block(_y_low(q, k, mu, granularity), ok, smax_ref, m_ref, l_ref)
 
     @pl.when(j == n_k - 1)
     def _finish():
@@ -392,7 +428,7 @@ def _pre_stats_kernel(bt_ref, starts_ref, ql_ref, q_ref, k_ref,
 
 
 def _pre_kernel(bt_ref, starts_ref, ql_ref, tau_ref, q_ref, k_ref, v_ref,
-                smax_ref, mlow_ref, llow_ref, o_ref, nsel_ref,
+                smax_in, mlow_in, llow_in, o_ref, nsel_ref,
                 acc_ref, m_ref, l_ref, cnt_ref,
                 *, H, bs, wq, n_k, lamp, mu, granularity, rule,
                 n_ref_ln, scale, window, Tk):
@@ -412,40 +448,19 @@ def _pre_kernel(bt_ref, starts_ref, ql_ref, tau_ref, q_ref, k_ref, v_ref,
     @pl.when(live)
     def _block():
         q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0, :, 0].astype(jnp.float32)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        if lamp:
-            y_low = _y_low(q, k, mu, granularity)
-            # row_lengths as in attention_lamp: clip(qi + 1, 0, window|Tk)
-            n_row = jnp.clip(qi[:, :1] + 1, 0, Tk if window is None else window)
-            sel = _select(y_low, ok, smax_ref[0][:, None],
-                          mlow_ref[0][:, None], llow_ref[0][:, None], n_row,
-                          rule=rule, tau=tau_ref[0], n_ref=n_ref_ln)
-            if rule == "none":
-                y = y_low
-            else:
-                y_exact = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                y = jnp.where(sel, y_exact, y_low)
-            cnt_ref[...] += jnp.sum(sel.astype(jnp.float32), axis=-1)
-        else:
-            y = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-        y = jnp.where(ok, y, _NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(y, axis=-1))
-        p = jnp.where(ok, jnp.exp(y - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        k = k_ref[0, 0].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
+        # row_lengths as in attention_lamp: clip(qi + 1, 0, window|Tk)
+        n_row = jnp.clip(qi[:, :1] + 1, 0, Tk if window is None else window)
+        y = _lamp_logits(q, k, ok, smax_in[0], mlow_in[0], llow_in[0], n_row,
+                         cnt_ref, lamp=lamp, mu=mu, granularity=granularity,
+                         rule=rule, tau=tau_ref[0], n_ref=n_ref_ln)
+        _softmax_block(y, ok, v, acc_ref, m_ref, l_ref)
 
     @pl.when(j == n_k - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], _TINY)[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], _TINY)
+                    ).astype(o_ref.dtype)
         nsel_ref[0] = cnt_ref[...]
 
 
@@ -455,17 +470,17 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
                             site: LampSite, *, tau=None, qlens=None,
                             window: Optional[int] = None,
                             block_q: Optional[int] = None,
-                            interpret: bool = True,
+                            interpret: Optional[bool] = None,
                             ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Windowed-prefill / mixed-row attention straight off the paged arena.
 
     q: (B, H, W, hd) -- query row w of sequence b sits at absolute position
     starts[b] + w and attends causally to positions 0..starts[b]+w of the
     row's block table (the cached prefix plus this window's just-written
-    KV). Padded rows are computed like the gather path and discarded by the
-    caller. Returns (out (B, H, W, hd) float32, n_selected (B, W) float32
-    summed over heads and keys) -- the ``attention_lamp(reduce=False)``
-    telemetry contract.
+    KV); arena_k/v: (n_blocks, Hkv, block_size, hd). Padded rows are
+    computed like the gather path and discarded by the caller. Returns
+    (out (B, H, W, hd) float32, n_selected (B, W) float32 summed over heads
+    and keys) -- the ``attention_lamp(reduce=False)`` telemetry contract.
 
     `tau` (optional *traced* scalar) overrides the static ``site.tau`` via
     a scalar-prefetch operand into the selection pass, keeping live
@@ -479,12 +494,16 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
     positions are bit-identical to qlens == W (skipped blocks are exact
     no-ops for live rows, see `_pre_mask`). ``qlens=None`` means every row
     fills the bucket -- the historical behavior, bit-for-bit.
+
+    On a TPU the query tile `block_q` must be W or a multiple of 8 (Mosaic
+    tiling); `interpret=None` follows the backend.
     """
     B, H, W, hd = q.shape
-    _, bs, Hkv, _ = arena_k.shape
+    _, Hkv, bs, _ = arena_k.shape
     n_max = block_tables.shape[1]
     rep = H // Hkv
     scale = hd ** -0.5
+    interpret = resolve_interpret(interpret)
     wq = W if block_q is None else min(block_q, W)
     if W % wq:
         raise ValueError(f"W={W} % block_q={wq}")
@@ -509,15 +528,15 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
         hi = jnp.minimum((q0 + qe - 1) // bs, n_max - 1)
         lo = 0 if window is None else \
             jnp.minimum(jnp.maximum(q0 - window + 1, 0) // bs, hi)
-        return (bt_ref[b, jnp.clip(j, lo, hi)], 0, (i % H) // rep, 0)
+        return (bt_ref[b, jnp.clip(j, lo, hi)], (i % H) // rep, 0, 0)
 
     q_spec = pl.BlockSpec((1, wq, hd), lambda i, t, j, *_: (i, t, 0))
-    kv_spec = pl.BlockSpec((1, bs, 1, hd), kv_map)
-    row_spec = pl.BlockSpec((1, wq), lambda i, t, j, *_: (i, t))
+    kv_spec = pl.BlockSpec((1, 1, bs, hd), kv_map)
+    row_spec = pl.BlockSpec((1, wq, 1), lambda i, t, j, *_: (i, t, 0))
+    row_shape = jax.ShapeDtypeStruct((B * H, W, 1), jnp.float32)
 
     if need_stats:
-        row_shape = jax.ShapeDtypeStruct((B * H, W), jnp.float32)
-        smax, m_low, l_low = pl.pallas_call(
+        stats = pl.pallas_call(
             functools.partial(_pre_stats_kernel, H=H, bs=bs, wq=wq, n_k=n_max,
                               mu=site.mu, granularity=site.granularity,
                               scale=scale, window=window),
@@ -526,13 +545,13 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
                 grid=(B * H, n_q, n_max),
                 in_specs=[q_spec, kv_spec],
                 out_specs=[row_spec] * 3,
-                scratch_shapes=[pltpu.VMEM((wq,), jnp.float32)] * 3,
+                scratch_shapes=[pltpu.VMEM((wq, 1), jnp.float32)] * 3,
             ),
             out_shape=[row_shape] * 3,
             interpret=interpret,
         )(bt, st, ql, qf, arena_k)
     else:
-        smax = m_low = l_low = jnp.zeros((B * H, W), jnp.float32)
+        stats = [jnp.zeros((B * H, W, 1), jnp.float32)] * 3
 
     out, nsel = pl.pallas_call(
         functools.partial(_pre_kernel, H=H, bs=bs, wq=wq, n_k=n_max,
@@ -542,24 +561,21 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
             grid=(B * H, n_q, n_max),
-            in_specs=[q_spec, kv_spec, kv_spec, row_spec, row_spec, row_spec],
-            out_specs=[
-                pl.BlockSpec((1, wq, hd), lambda i, t, j, *_: (i, t, 0)),
-                row_spec,
-            ],
+            in_specs=[q_spec, kv_spec, kv_spec] + [row_spec] * 3,
+            out_specs=[q_spec, row_spec],
             scratch_shapes=[
                 pltpu.VMEM((wq, hd), jnp.float32),  # acc
-                pltpu.VMEM((wq,), jnp.float32),     # m
-                pltpu.VMEM((wq,), jnp.float32),     # l
-                pltpu.VMEM((wq,), jnp.float32),     # nsel counts
+                pltpu.VMEM((wq, 1), jnp.float32),   # m
+                pltpu.VMEM((wq, 1), jnp.float32),   # l
+                pltpu.VMEM((wq, 1), jnp.float32),   # nsel counts
             ],
         ),
         out_shape=[
             jax.ShapeDtypeStruct((B * H, W, hd), jnp.float32),
-            jax.ShapeDtypeStruct((B * H, W), jnp.float32),
+            row_shape,
         ],
         interpret=interpret,
-    )(bt, st, ql, tau_arr, qf, arena_k, arena_v, smax, m_low, l_low)
+    )(bt, st, ql, tau_arr, qf, arena_k, arena_v, *stats)
     return (out.reshape(B, H, W, hd),
             jnp.sum(nsel.reshape(B, H, W), axis=1))
 
@@ -568,7 +584,7 @@ def paged_mixed_attention(q, arena_k, arena_v, block_tables, starts, qlens,
                           site: LampSite, *, tau=None,
                           window: Optional[int] = None,
                           block_q: Optional[int] = None,
-                          interpret: bool = True,
+                          interpret: Optional[bool] = None,
                           ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Mixed-row paged attention: one grid over decode rows (qlens[b] == 1),
     chunked-prefill windows (qlens[b] == w) and speculative verify rows

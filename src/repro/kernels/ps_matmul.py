@@ -13,7 +13,7 @@ VMEM scratch.
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +21,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.numerics import round_to_mantissa
+
+from .backend import resolve_interpret
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, mu: int, n_k: int):
@@ -45,7 +47,7 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, mu: int, n_k: int):
     "mu", "block_m", "block_n", "block_k", "interpret"))
 def ps_matmul(a: jnp.ndarray, b: jnp.ndarray, *, mu: int = 7,
               block_m: int = 128, block_n: int = 128, block_k: int = 128,
-              interpret: bool = True) -> jnp.ndarray:
+              interpret: Optional[bool] = None) -> jnp.ndarray:
     """a (M, K) @ b (K, N) -> (M, N) float32 on the PS(mu) grid."""
     M, K = a.shape
     K2, N = b.shape
@@ -68,5 +70,5 @@ def ps_matmul(a: jnp.ndarray, b: jnp.ndarray, *, mu: int = 7,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(a, b)

@@ -8,10 +8,13 @@ in JAX (DESIGN.md Sec 3), but the normalization is fused here.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from .backend import resolve_interpret
 
 
 def _kernel(x_ref, w_ref, o_ref, *, eps: float):
@@ -23,7 +26,8 @@ def _kernel(x_ref, w_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "eps", "interpret"))
 def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
-            block_rows: int = 256, interpret: bool = True) -> jnp.ndarray:
+            block_rows: int = 256,
+            interpret: Optional[bool] = None) -> jnp.ndarray:
     """x: (..., d), w: (d,). Rows are tiled block_rows at a time in VMEM."""
     orig_shape = x.shape
     d = x.shape[-1]
@@ -44,6 +48,6 @@ def rmsnorm(x: jnp.ndarray, w: jnp.ndarray, *, eps: float = 1e-6,
         ],
         out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(((rows + pad), d), x.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x2, w)
     return out[:rows].reshape(orig_shape)

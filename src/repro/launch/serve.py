@@ -31,6 +31,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, list_archs, reduced as reduce_cfg
+from repro.launch.compile_cache import init_compile_cache
 from repro.models import api
 from repro.obs import ObsConfig
 from repro.serving import (AuditConfig, EngineConfig, FaultConfig,
@@ -139,7 +140,10 @@ def serve_stream(engine: LampEngine, stream, *,
     return outputs
 
 
-def main():
+def main(argv: Optional[List[str]] = None) -> dict:
+    """Serve one synthetic stream and print its report. Returns the final
+    `engine.stats(exact=True)`; raises SystemExit(non-zero) when a request
+    failed or was rejected, or the stream was interrupted or gave up."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=servable_archs())
     ap.add_argument("--reduced", action="store_true")
@@ -268,8 +272,9 @@ def main():
     ap.add_argument("--jax-profile", default="",
                     help="wrap the run in jax.profiler.trace writing to "
                          "this directory (TensorBoard/XPlane format)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    cache_dir = init_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
@@ -310,7 +315,10 @@ def main():
 
     rng = np.random.default_rng(args.seed)
     stream = build_stream(rng, args, cfg.vocab)
-    print(f"[serve] arch={cfg.name} lamp={not args.no_lamp} "
+    dev = jax.devices()[0]
+    print(f"[serve] platform={dev.platform} device_kind={dev.device_kind!r} "
+          f"devices={len(jax.devices())} compile_cache={cache_dir} "
+          f"arch={cfg.name} lamp={not args.no_lamp} "
           f"qps={args.qps} requests={args.num_requests} "
           f"pool={engine.pool.num_total}x{engine.pool.block_size} blocks "
           f"prefix_cache={args.prefix_cache} "
@@ -456,6 +464,7 @@ def main():
         exit_code = 1
     if exit_code:
         raise SystemExit(exit_code)
+    return s
 
 
 if __name__ == "__main__":

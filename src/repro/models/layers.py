@@ -215,11 +215,8 @@ def attention_decode_sublayer(cfg, p, x, *, cache_k, cache_v, length,
     # a >1 'model' mesh axis, run the shard_map distributed online softmax
     # (grouped GQA, cache read once, O(B*H*hd) combine) instead of letting
     # XLA all-gather the cache (EXPERIMENTS Sec Perf, hillclimb B).
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        names = getattr(am, "axis_names", ()) if am is not None else ()
-    except Exception:
-        names = ()
+    am = jax.sharding.get_abstract_mesh()
+    names = am.axis_names
     S = use_k.shape[1]
     from repro.core.attention import baseline_mode
     if ("model" in names and am.shape["model"] > 1
@@ -242,6 +239,32 @@ def attention_decode_sublayer(cfg, p, x, *, cache_k, cache_v, length,
     return out @ p["wo"], cache_k, cache_v, rate
 
 
+def write_paged_kv(arena, blk, off, x):
+    """Scatter per-token K or V rows x (..., Hkv, hd) into a head-major
+    (n_blocks, Hkv, block_size, hd) arena at block `blk`, in-block offset
+    `off` (both shaped like x's leading axes)."""
+    return arena.at[blk, :, off].set(x.astype(arena.dtype))
+
+
+def paged_kv_view(arena, block_tables, n_rep: int):
+    """Each row's gathered (B, H, n_max * block_size, hd) view of a
+    head-major (n_blocks, Hkv, block_size, hd) arena, KV heads repeated
+    n_rep times for GQA. Flat index t of the view == absolute position t."""
+    B, n_max = block_tables.shape
+    _, Hkv, bs, hd = arena.shape
+    view = jnp.moveaxis(arena[block_tables], 2, 1)    # (B, Hkv, n_max, bs, hd)
+    return _repeat_kv(view.reshape(B, Hkv, n_max * bs, hd), n_rep)
+
+
+def require_kernel_site(site: LampSite) -> None:
+    """kernel="pallas" runs the fused kernel or fails: a site the kernel
+    does not implement never runs the gather path in its place."""
+    from repro.kernels.paged_attention import supports_site
+    if not supports_site(site):
+        raise ValueError(f"kernel='pallas' does not implement LAMP rule "
+                         f"{site.rule!r}; use kernel='gather'")
+
+
 def paged_attention_decode_sublayer(cfg, p, x, *, arena_k, arena_v,
                                     block_tables, lengths,
                                     lamp_site: LampSite,
@@ -251,7 +274,7 @@ def paged_attention_decode_sublayer(cfg, p, x, *, arena_k, arena_v,
     """Single-token decode against a paged KV arena (one layer).
 
     x: (R, 1, d) hidden states for R slots of a continuous batch.
-    arena_k/v: (n_blocks, block_size, Hkv, hd) shared block arena.
+    arena_k/v: (n_blocks, Hkv, block_size, hd) shared head-major arena.
     block_tables: (R, n_max) int32; row r lists the arena blocks holding
         sequence r's KV in position order (0 = reserved null block for
         padding — never read thanks to the length mask, writes to it are
@@ -265,8 +288,8 @@ def paged_attention_decode_sublayer(cfg, p, x, *, arena_k, arena_v,
     computation bit-identical to the dense-cache path for valid positions.
     kernel="pallas": the fused paged-attention kernel reads live arena
     blocks directly through the block-table index map (no gather, masked
-    blocks skipped); falls back to gather for sites the kernel does not
-    implement (the benchmark-only "random" rule).
+    blocks skipped); a site the kernel does not implement (the
+    benchmark-only "random" rule) raises instead of running gather.
     tau: optional traced scalar overriding lamp_site.tau (the serving policy
     controller threads per-layer thresholds through the jitted steps so
     moving them never recompiles).
@@ -274,19 +297,19 @@ def paged_attention_decode_sublayer(cfg, p, x, *, arena_k, arena_v,
     """
     R = x.shape[0]
     H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    bs = arena_k.shape[1]
+    bs = arena_k.shape[2]
     q, k, v = _project_qkv(cfg, p, x, lengths[:, None])
     ridx = jnp.arange(R)
     blk = block_tables[ridx, lengths // bs]
     off = lengths % bs
-    arena_k = arena_k.at[blk, off].set(k[:, 0].astype(arena_k.dtype))
-    arena_v = arena_v.at[blk, off].set(v[:, 0].astype(arena_v.dtype))
+    arena_k = write_paged_kv(arena_k, blk, off, k[:, 0])
+    arena_v = write_paged_kv(arena_v, blk, off, v[:, 0])
     qh = jnp.swapaxes(q, 1, 2)                                # (R,H,1,hd)
     window = window if window is not None else cfg.window
 
-    from repro.kernels.paged_attention import supports_site
-    if kernel == "pallas" and supports_site(lamp_site):
+    if kernel == "pallas":
         from repro.kernels import ops as KOPS
+        require_kernel_site(lamp_site)
         eff = lengths + 1
         out, nsel = KOPS.paged_decode_attention(
             qh, arena_k, arena_v, block_tables, eff, lamp_site, tau=tau,
@@ -294,10 +317,8 @@ def paged_attention_decode_sublayer(cfg, p, x, *, arena_k, arena_v,
         cap = eff if window is None else jnp.minimum(eff, window)
         nval = (cap * H).astype(jnp.float32)
     else:
-        ks = arena_k[block_tables].reshape(R, -1, Hkv, hd)
-        vs = arena_v[block_tables].reshape(R, -1, Hkv, hd)
-        kh = _repeat_kv(jnp.moveaxis(ks, 2, 1), H // Hkv)     # (R,H,S,hd)
-        vh = _repeat_kv(jnp.moveaxis(vs, 2, 1), H // Hkv)
+        kh = paged_kv_view(arena_k, block_tables, H // Hkv)   # (R,H,S,hd)
+        vh = paged_kv_view(arena_v, block_tables, H // Hkv)
         out, aux = A.decode_attention_lamp(qh, kh, vh, lengths + 1, lamp_site,
                                            window=window, reduce=False,
                                            tau=tau)

@@ -26,6 +26,9 @@ from repro.core.policy import LampSite
 
 from .layers import dtype_of
 
+# the router LAMP site's recompute and its LAMP-off reference are FP32
+_HI = jax.lax.Precision.HIGHEST
+
 
 def moe_params(cfg, key) -> Dict[str, jnp.ndarray]:
     d, ff, E = cfg.d_model, cfg.d_ff, cfg.n_experts
@@ -45,13 +48,14 @@ def router_probs_lamp(x2d: jnp.ndarray, router_w: jnp.ndarray,
     """Router logits with LAMP evaluation. x2d: (T, d). Returns (probs, rate)."""
     xf = x2d.astype(jnp.float32)
     if not site.enabled:
-        return jax.nn.softmax(xf @ router_w, axis=-1), jnp.zeros(())
+        return jax.nn.softmax(jnp.matmul(xf, router_w, precision=_HI),
+                              axis=-1), jnp.zeros(())
     y_low = dot_ps(xf, router_w, site.mu, granularity=site.granularity)
     if site.rule == "relaxed":
         mask = L.select_softmax_relaxed(y_low, site.tau)
     else:
         mask = L.select_softmax_strict(y_low, site.tau)
-    y = jnp.where(mask, xf @ router_w, y_low)
+    y = jnp.where(mask, jnp.matmul(xf, router_w, precision=_HI), y_low)
     return jax.nn.softmax(y, axis=-1), jnp.mean(mask.astype(jnp.float32))
 
 
@@ -144,11 +148,8 @@ def moe_dispatch(cfg, p, x: jnp.ndarray, *, lamp_site: LampSite,
     >1 `model` mesh axis is ambient (scales; multi-pod safe), else the
     einsum/scatter path (single device, tests, REPRO_BASELINE=1)."""
     from repro.core.attention import baseline_mode
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        names = getattr(am, "axis_names", ()) if am is not None else ()
-    except Exception:
-        names = ()
+    am = jax.sharding.get_abstract_mesh()
+    names = am.axis_names
     if ("model" in names and am.shape["model"] > 1
             and cfg.n_experts % am.shape["model"] == 0
             and not baseline_mode()):

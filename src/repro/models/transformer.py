@@ -298,12 +298,13 @@ def decode_step(cfg, params, cache: Dict[str, Any], tokens: jnp.ndarray, *,
 # Paged KV-cache serving (block tables over a shared arena)
 # ---------------------------------------------------------------------------
 #
-# Layout: one arena per layer, (L, n_blocks, block_size, Hkv, hd). A sequence
-# owns an ordered list of blocks; flat index t within the gathered view of a
-# row's block table == absolute token position t, so attention semantics are
-# identical to the dense cache (padded tail masked by `lengths`). Block 0 is
-# reserved as a null/scratch block: block-table padding points at it and
-# padded slots write into it.
+# Layout: one head-major arena per layer, (L, n_blocks, Hkv, block_size, hd),
+# so one (block, kv head) tile is a (block_size, hd) slab the Pallas kernel
+# DMAs whole. A sequence owns an ordered list of blocks; flat index t within
+# the gathered view of a row's block table == absolute token position t, so
+# attention semantics are identical to the dense cache (padded tail masked
+# by `lengths`). Block 0 is reserved as a null/scratch block: block-table
+# padding points at it and padded slots write into it.
 
 def _serving_site(site: LampSite) -> LampSite:
     """The App C.4 'random' control arm needs a resampled key per call and is
@@ -317,8 +318,8 @@ def init_paged_cache(cfg, n_blocks: int, block_size: int,
                      dtype=jnp.bfloat16) -> Dict[str, Any]:
     L, Hkv, hd = cfg.n_layers, cfg.n_kv_heads, cfg.hd
     return {
-        "k": jnp.zeros((L, n_blocks, block_size, Hkv, hd), dtype),
-        "v": jnp.zeros((L, n_blocks, block_size, Hkv, hd), dtype),
+        "k": jnp.zeros((L, n_blocks, Hkv, block_size, hd), dtype),
+        "v": jnp.zeros((L, n_blocks, Hkv, block_size, hd), dtype),
     }
 
 
@@ -376,9 +377,9 @@ def paged_prefill_window(cfg, params, tokens: jnp.ndarray,
     the fused paged-attention kernel instead: blocks are DMA'd through the
     block-table index map and fully-masked blocks (past each q-tile's
     causal bound) are skipped, with the same row-wise numerics -- outputs
-    stay token-identical to the gather path (differential-tested). Sites
-    the kernel does not implement (the "random" control rule) fall back
-    to gather.
+    stay token-identical to the gather path (differential-tested). A site
+    the kernel does not implement raises; serving never builds one (the
+    "random" control rule maps to strict, see `_serving_site`).
 
     Returns (last_logits (B, 1, V), arena, (n_selected (B,), n_valid (B,)))
     with last_logits at each row's final valid *window* position (only
@@ -491,7 +492,7 @@ def _paged_window_apply(cfg, params, tokens, arena, block_tables, starts,
     between steps without changing the jit cache key."""
     B, W = tokens.shape
     n_max = block_tables.shape[1]
-    bs = arena["k"].shape[2]
+    bs = arena["k"].shape[3]
     positions = starts[:, None] + jnp.arange(W)[None, :]              # (B, W)
     x = LY.embed(cfg, params["embed"], tokens, positions)
     ctx = _ctx(cfg, positions, use_lamp, "full", moe_groups)
@@ -502,9 +503,10 @@ def _paged_window_apply(cfg, params, tokens, arena, block_tables, starts,
                     jnp.take_along_axis(block_tables, blk_idx, axis=1), 0)
     off = jnp.where(valid_tok, positions % bs, 0)
     qmask = valid_tok.astype(jnp.float32)
-    H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    from repro.kernels.paged_attention import supports_site
-    use_pallas = kernel == "pallas" and supports_site(site)
+    H, Hkv = cfg.n_heads, cfg.n_kv_heads
+    use_pallas = kernel == "pallas"
+    if use_pallas:
+        LY.require_kernel_site(site)
     if taus is None:
         taus = jnp.full((cfg.n_layers,), float(site.tau), jnp.float32)
 
@@ -513,8 +515,8 @@ def _paged_window_apply(cfg, params, tokens, arena, block_tables, starts,
         p_l, ck, cv, tau_l = xs
         h = LY.apply_norm(cfg, xc, p_l, "ln1")
         q, k, v = LY._project_qkv(cfg, p_l["attn"], h, positions)
-        ck = ck.at[blk, off].set(k.astype(ck.dtype))
-        cv = cv.at[blk, off].set(v.astype(cv.dtype))
+        ck = LY.write_paged_kv(ck, blk, off, k)
+        cv = LY.write_paged_kv(cv, blk, off, v)
         qh = jnp.swapaxes(q, 1, 2)
         from repro.core import attention as CA
         if use_pallas:
@@ -538,10 +540,8 @@ def _paged_window_apply(cfg, params, tokens, arena, block_tables, starts,
         else:
             # gather the full per-row view (cached prefix + this window);
             # gathered flat index t == absolute position t, as in decode
-            ks = ck[block_tables].reshape(B, n_max * bs, Hkv, hd)
-            vs = cv[block_tables].reshape(B, n_max * bs, Hkv, hd)
-            kh = LY._repeat_kv(jnp.moveaxis(ks, 2, 1), H // Hkv)
-            vh = LY._repeat_kv(jnp.moveaxis(vs, 2, 1), H // Hkv)
+            kh = LY.paged_kv_view(ck, block_tables, H // Hkv)
+            vh = LY.paged_kv_view(cv, block_tables, H // Hkv)
             if site.enabled:
                 o, aux = CA.attention_lamp(qh, kh, vh, site, causal=True,
                                            window=cfg.window, offset=starts,
@@ -613,7 +613,7 @@ def paged_audit_window(cfg, params, tokens, arena, block_tables, starts,
     """
     B, W = tokens.shape
     n_max = block_tables.shape[1]
-    bs = arena["k"].shape[2]
+    bs = arena["k"].shape[3]
     positions = starts[:, None] + jnp.arange(W)[None, :]              # (B, W)
     ctx = _ctx(cfg, positions, True, "full", moe_groups)
     site = _serving_site(ctx.lamp_kq)
@@ -624,7 +624,7 @@ def paged_audit_window(cfg, params, tokens, arena, block_tables, starts,
     blk = jnp.where(valid_tok,
                     jnp.take_along_axis(block_tables, blk_idx, axis=1), 0)
     off_idx = jnp.where(valid_tok, positions % bs, 0)
-    H, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
+    H, Hkv = cfg.n_heads, cfg.n_kv_heads
     if taus is None:
         taus = jnp.full((cfg.n_layers,), float(site.tau), jnp.float32)
 
@@ -642,11 +642,8 @@ def paged_audit_window(cfg, params, tokens, arena, block_tables, starts,
     from repro.core import attention as CA
 
     def gathered(ck, cv):
-        ks = ck[block_tables].reshape(B, n_max * bs, Hkv, hd)
-        vs = cv[block_tables].reshape(B, n_max * bs, Hkv, hd)
-        kh = LY._repeat_kv(jnp.moveaxis(ks, 2, 1), H // Hkv)
-        vh = LY._repeat_kv(jnp.moveaxis(vs, 2, 1), H // Hkv)
-        return kh, vh
+        return (LY.paged_kv_view(ck, block_tables, H // Hkv),
+                LY.paged_kv_view(cv, block_tables, H // Hkv))
 
     def flat(o):
         # (B, H, W, hd) attention layout -> (B, W, H*hd) feature rows
@@ -667,8 +664,8 @@ def paged_audit_window(cfg, params, tokens, arena, block_tables, starts,
         # ref-stream intermediates the shadow computation needs
         h = LY.apply_norm(cfg, xc, p_l, "ln1")
         q, k, v = LY._project_qkv(cfg, p_l["attn"], h, positions)
-        ck = ck.at[blk, off_idx].set(k.astype(ck.dtype))
-        cv = cv.at[blk, off_idx].set(v.astype(cv.dtype))
+        ck = LY.write_paged_kv(ck, blk, off_idx, k)
+        cv = LY.write_paged_kv(cv, blk, off_idx, v)
         qh = jnp.swapaxes(q, 1, 2)
         kh, vh = gathered(ck, cv)
         o = attn(qh, kh, vh, lamp_site, tau_l)

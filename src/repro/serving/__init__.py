@@ -5,7 +5,7 @@ vLLM-style serving architecture over the repro model stack:
   request.py   -- Request/Sequence lifecycle (WAITING -> PREFILL -> DECODE ->
                   FINISHED), per-request sampling params and LAMP stats
   kv_pool.py   -- paged KV-cache pool: refcounted block tables over a shared
-                  (L, n_blocks, block_size, Hkv, hd) arena, chain-hashed
+                  (L, n_blocks, Hkv, block_size, hd) arena, chain-hashed
                   prefix index with copy-on-write sharing
   scheduler.py -- continuous-batching scheduler: FCFS admission by free-block
                   budget with prefix matching, chunked prefill windows,

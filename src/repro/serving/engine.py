@@ -1024,8 +1024,8 @@ class LampEngine:
         if pos:
             blocks = jnp.asarray([seq.block_ids[p // bs] for p in pos])
             offs = jnp.asarray([p % bs for p in pos])
-            self.pool.k = self.pool.k.at[:, blocks, offs].set(jnp.nan)
-            self.pool.v = self.pool.v.at[:, blocks, offs].set(jnp.nan)
+            self.pool.k = self.pool.k.at[:, blocks, :, offs].set(jnp.nan)
+            self.pool.v = self.pool.v.at[:, blocks, :, offs].set(jnp.nan)
         health = np.array(health, np.float64)
         health[row] = np.nan
         inj.record(step_id, "nan", req=seq.req_id, start=start, width=width)

@@ -1,9 +1,9 @@
 """Paged KV-cache pool: refcounted, prefix-cached block tables over a shared
 per-layer arena.
 
-The arena is a pair of device arrays shaped (L, n_blocks, block_size, Hkv,
-hd) (see `transformer.init_paged_cache`). The pool manages the *host-side*
-free list and hands out ordered block lists; sequences index the arena
+The arena is a pair of head-major device arrays shaped (L, n_blocks, Hkv,
+block_size, hd) (see `transformer.init_paged_cache`). The pool manages the
+*host-side* free list and hands out ordered block lists; sequences index the arena
 through (padded) block tables inside the jitted model functions.
 
 Block 0 is reserved as the null/scratch block: block-table padding points at

@@ -401,8 +401,11 @@ class PolicyController:
             dlog = np.clip(c.gain * np.log((self._ema + eps)
                                            / (targets + eps)),
                            -c.max_step, c.max_step)
-            # deadband: inside the tolerance around the setpoint, hold
-            dlog[np.abs(self._ema - targets) <= c.deadband * targets] = 0.0
+            # deadband: inside the tolerance around the setpoint, hold. The
+            # edge counts as inside up to float32 resolution: a rate of
+            # exactly target * (1 + deadband) must not round its way out
+            band = c.deadband * targets * (1.0 + 1e-6)
+            dlog[np.abs(self._ema - targets) <= band] = 0.0
         new = np.clip(self._log_tau + dlog,
                       np.log(c.tau_min), np.log(c.tau_max))
         moved = bool(np.any(new != self._log_tau))

@@ -1,30 +1,16 @@
 import os
 
-import pytest
-
 # Tests run on the single real CPU device (NOT the 512-device dry-run world);
-# keep compilation deterministic and quiet.
+# keep compilation deterministic and quiet. Pallas kernels follow the backend
+# (kernels/backend.resolve_interpret): on the CPU they run through the Pallas
+# interpreter, so tier-1 exercises the real kernel bodies -- the same code
+# that compiles to Mosaic on a TPU.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ.setdefault("XLA_FLAGS", "--xla_cpu_multi_thread_eigen=false")
-
-# Force every Pallas kernel (flash_decode, lamp_attention, the paged
-# attention family, ...) through pl.pallas_call(..., interpret=True): tier-1
-# runs the real kernel bodies on CPU instead of skipping them, and tests
-# that flip engine/model code onto the "pallas" kernel path exercise the
-# same code that compiles to Mosaic on TPU. kernels/ops._default_interpret
-# reads this at call time.
-os.environ.setdefault("REPRO_PALLAS_INTERPRET", "1")
 
 import jax
 
 jax.config.update("jax_enable_x64", False)
-
-
-@pytest.fixture(autouse=True)
-def _pallas_interpret_on_cpu(monkeypatch):
-    """Keep the interpret flag pinned even for tests that scrub os.environ."""
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET",
-                       os.environ.get("REPRO_PALLAS_INTERPRET", "1"))
 
 # Hypothesis profiles (no-op when hypothesis is not installed). Tier-1 / CI
 # run the pinned deterministic "ci" profile (derandomized, 500 examples) via

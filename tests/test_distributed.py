@@ -15,7 +15,7 @@ def _run(body: str) -> str:
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        os.environ.pop("JAX_PLATFORMS", None)
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import jax, jax.numpy as jnp, numpy as np
     """) + textwrap.dedent(body)
     env = dict(os.environ)

@@ -31,6 +31,7 @@ from repro.core.attention import (attention_lamp, attention_reference,
 from repro.core.policy import LampSite
 from repro.kernels import ops
 from repro.kernels.paged_attention import decode_kv_bytes, supports_site
+from repro.models.layers import require_kernel_site
 
 H, HKV, HD = 4, 2, 16
 
@@ -69,18 +70,30 @@ def _repeat_kv(t, n):
     return jnp.repeat(t, n, axis=1) if n > 1 else t
 
 
+def _grid(x):
+    """Snap random values to multiples of 2^-6 in [-8, 8]: every q.k product
+    and every partial sum of one is then exact in float32, so the kernel's
+    per-block dot and the reference's one batched dot give bitwise-equal
+    logits whatever order XLA accumulates them in. Off the grid the two
+    orders can differ in the last ulp, and the PS(mu) rounding of y_low
+    turns a 1-ulp difference at a rounding midpoint into a 2^-mu one -- a
+    flipped LAMP selection the exact-count assertions would misread as a
+    kernel bug."""
+    return np.clip(np.round(x * 64) / 64, -8, 8)
+
+
 def make_paged(seed, lengths, bs, n_max, *, span=None):
-    """Random arena + per-row block tables. Row r owns ceil(span[r]/bs)
-    distinct shuffled blocks (block 0 stays the null block); the rest of the
-    table is null-padded. span defaults to lengths (decode); prefill passes
-    starts + window width."""
+    """Random head-major arena (n_blocks, Hkv, bs, hd) + per-row block
+    tables. Row r owns ceil(span[r]/bs) distinct shuffled blocks (block 0
+    stays the null block); the rest of the table is null-padded. span
+    defaults to lengths (decode); prefill passes starts + window width."""
     rng = np.random.default_rng(seed)
     R = len(lengths)
     span = list(lengths) if span is None else list(span)
     n_blocks = 1 + R * n_max
-    arena_k = jnp.asarray(rng.normal(size=(n_blocks, bs, HKV, HD)) * 1.5,
+    arena_k = jnp.asarray(_grid(rng.normal(size=(n_blocks, HKV, bs, HD)) * 1.5),
                           jnp.float32)
-    arena_v = jnp.asarray(rng.normal(size=(n_blocks, bs, HKV, HD)),
+    arena_v = jnp.asarray(_grid(rng.normal(size=(n_blocks, HKV, bs, HD))),
                           jnp.float32)
     perm = rng.permutation(np.arange(1, n_blocks))
     bt = np.zeros((R, n_max), np.int32)
@@ -91,12 +104,14 @@ def make_paged(seed, lengths, bs, n_max, *, span=None):
 
 
 def gathered_heads(arena_k, arena_v, bt):
-    R = bt.shape[0]
-    ks = arena_k[bt].reshape(R, -1, HKV, HD)
-    vs = arena_v[bt].reshape(R, -1, HKV, HD)
-    kh = _repeat_kv(jnp.moveaxis(ks, 2, 1), H // HKV)
-    vh = _repeat_kv(jnp.moveaxis(vs, 2, 1), H // HKV)
-    return kh, vh
+    """(R, H, n_max * bs, hd) per-row views: block walk concatenated along
+    the position axis of each KV head, then repeated for GQA."""
+    def view(arena):
+        blocks = arena[bt]                          # (R, n_max, Hkv, bs, hd)
+        flat = jnp.concatenate([blocks[:, j] for j in range(bt.shape[1])],
+                               axis=2)              # (R, Hkv, n_max*bs, hd)
+        return _repeat_kv(flat, H // HKV)
+    return view(arena_k), view(arena_v)
 
 
 def check_decode(seed, lengths, bs, n_max, site, *, window=None,
@@ -105,7 +120,7 @@ def check_decode(seed, lengths, bs, n_max, site, *, window=None,
     arena_k, arena_v, bt = make_paged(seed, lengths, bs, n_max)
     lengths = jnp.asarray(lengths, jnp.int32)
     rng = np.random.default_rng(seed + 7)
-    q = jnp.asarray(rng.normal(size=(len(lengths), H, 1, HD)) * 1.5,
+    q = jnp.asarray(_grid(rng.normal(size=(len(lengths), H, 1, HD)) * 1.5),
                     jnp.float32)
 
     out, nsel = ops.paged_decode_attention(q, arena_k, arena_v, bt, lengths,
@@ -134,7 +149,7 @@ def check_prefill(seed, starts, bs, n_max, site, *, W=8, window=None,
     arena_k, arena_v, bt = make_paged(seed, span, bs, n_max, span=span)
     st = jnp.asarray(starts, jnp.int32)
     rng = np.random.default_rng(seed + 13)
-    q = jnp.asarray(rng.normal(size=(len(starts), H, W, HD)) * 1.5,
+    q = jnp.asarray(_grid(rng.normal(size=(len(starts), H, W, HD)) * 1.5),
                     jnp.float32)
 
     out, nsel = ops.paged_prefill_attention(q, arena_k, arena_v, bt, st, site,
@@ -263,6 +278,10 @@ def test_supports_site_gate():
     assert not supports_site(LampSite(enabled=True, rule="random"))
     for name in SITES:
         assert supports_site(SITES[name])
+    # kernel="pallas" fails on a site it does not implement; it never runs
+    # the gather path in its place
+    with pytest.raises(ValueError, match="random"):
+        require_kernel_site(LampSite(enabled=True, rule="random"))
 
 
 def test_decode_kv_bytes_model():

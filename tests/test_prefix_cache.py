@@ -108,6 +108,17 @@ class PoolHarness:
         shared/registered partial tail."""
         seq = self.seqs[sid]
         n_tokens = min(n_tokens, seq.cache_len)
+        # like admission, the caller guards the budget: copying a shared
+        # partial tail needs one allocatable block once the surplus is
+        # freed (the engine only rolls back into blocks the sequence wrote
+        # itself, which are private)
+        pool, keep = self.pool, self.pool.blocks_for(n_tokens)
+        cow = (n_tokens % pool.block_size and keep
+               and pool.needs_cow(seq.block_ids[keep - 1]))
+        freed = sum(1 for b in seq.block_ids[keep:]
+                    if pool.refcount.get(b, 0) == 1)
+        if cow and pool.num_free + freed < 1:
+            return
         seq.block_ids = self.pool.rollback(seq.block_ids, n_tokens)
         seq.cache_len = seq.prefill_cursor = n_tokens
 

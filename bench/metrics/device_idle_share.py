@@ -1,0 +1,7 @@
+"""Share (%) of the traced window in which no operation ran on the device."""
+
+
+def read(ctx):
+    if not ctx.red or not ctx.red["window_s"]:
+        return None
+    return 100.0 * (1.0 - ctx.red["busy_s"] / ctx.red["window_s"])

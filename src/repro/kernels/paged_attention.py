@@ -52,6 +52,12 @@ Each variant is therefore a pair of ``pallas_call``s:
       accumulates P@V, and counts selections per row (the engine's
       per-request recompute telemetry).
 
+Each ``pallas_call`` carries its pass as ``metadata`` (``LOOKAHEAD`` /
+``RECOMPUTE``): the compiled op keeps the entry's name
+(``paged_prefill_attention.N``) and its text gains
+``kernel_metadata={"lamp_pass":"lookahead"}`` or ``...:"recompute"}``, so a
+profiler trace tells the two passes apart.
+
 Because both passes recompute y_low identically, the pair implements the
 materialized-softmax rules exactly: outputs match the gather reference path
 to float32 softmax roundoff and selection counts match bit-for-bit for the
@@ -93,6 +99,11 @@ from .backend import resolve_interpret
 _NEG = -1e30
 _TINY = 1.1754944e-38  # float32 tiny: masked_softmax's normalizer clamp
 _HI = jax.lax.Precision.HIGHEST
+# which LAMP pass a pallas_call is: the tag rides in the compiled op's
+# frontend attributes (and so in its profiler trace text) while the op keeps
+# the name of the jitted entry
+LOOKAHEAD = {"lamp_pass": "lookahead"}
+RECOMPUTE = {"lamp_pass": "recompute"}
 
 
 def supports_site(site: LampSite) -> bool:
@@ -343,6 +354,7 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
             ),
             out_shape=[row_shape] * 3,
             interpret=interpret,
+            metadata=LOOKAHEAD,
         )(bt, lens, qf, arena_k)
     else:
         stats = [jnp.zeros((R * H, 1, 1), jnp.float32)] * 3
@@ -369,6 +381,7 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
             row_shape,
         ],
         interpret=interpret,
+        metadata=RECOMPUTE,
     )(bt, lens, tau_arr, qf, arena_k, arena_v, *stats)
     return (out.reshape(R, H, 1, hd),
             jnp.sum(nsel.reshape(R, H), axis=1))
@@ -549,6 +562,7 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
             ),
             out_shape=[row_shape] * 3,
             interpret=interpret,
+            metadata=LOOKAHEAD,
         )(bt, st, ql, qf, arena_k)
     else:
         stats = [jnp.zeros((B * H, W, 1), jnp.float32)] * 3
@@ -575,6 +589,7 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
             row_shape,
         ],
         interpret=interpret,
+        metadata=RECOMPUTE,
     )(bt, st, ql, tau_arr, qf, arena_k, arena_v, *stats)
     return (out.reshape(B, H, W, hd),
             jnp.sum(nsel.reshape(B, H, W), axis=1))
@@ -596,7 +611,8 @@ def paged_mixed_attention(q, arena_k, arena_v, block_tables, starts, qlens,
 
 
 # ---------------------------------------------------------------------------
-# Traffic model (benchmarks): KV bytes DMA'd per decode step, per layer
+# Traffic model (benchmarks): KV bytes DMA'd per decode step, per layer, and
+# the KV grid steps one pass walks
 # ---------------------------------------------------------------------------
 
 def decode_kv_bytes(lengths, *, n_max: int, block_size: int,
@@ -616,3 +632,37 @@ def decode_kv_bytes(lengths, *, n_max: int, block_size: int,
     live = int((hi - lo + 1).sum())
     fused = live * block_size * bytes_per_token * (3 if lamp else 2)
     return gather, fused
+
+
+def grid_blocks(starts, qlens, rows: int, Bb: int, n_max: int,
+                block_size: int, block_q: Optional[int] = None,
+                window: Optional[int] = None,
+                width: Optional[int] = None) -> Tuple[int, int]:
+    """(live, walked): the KV grid steps of one pass of
+    ``paged_prefill_attention`` (one head, one layer) that touch a live
+    block, and all the grid steps it walks.
+
+    The grid is (Bb rows, tiles, n_max blocks); a step is live when its
+    block index lies in the [lo, hi] range ``kv_map`` clamps to, for a tile
+    that holds live queries. Only the first `rows` of `starts` / `qlens`
+    are real: pad rows are walked, never live. ``block_q=None`` is the
+    engine's one tile per row; with a `block_q` the tiles per row are
+    `width` // block_q, so `width` (the bucket's W) is then required. A
+    decode launch is the case starts = lengths - 1, qlens = 1."""
+    import numpy as np
+    st = np.asarray(starts, np.int64)[:rows, None]
+    ql = np.asarray(qlens, np.int64)[:rows, None]
+    if block_q is None:
+        n_q, q0, qe = 1, st, ql
+    else:
+        if width is None:
+            raise ValueError("grid_blocks: block_q needs the bucket width")
+        wq = min(block_q, width)
+        n_q = width // wq
+        t = np.arange(n_q)[None, :] * wq
+        q0, qe = st + t, np.clip(ql - t, 0, wq)
+    hi = np.minimum((q0 + np.maximum(qe, 1) - 1) // block_size, n_max - 1)
+    lo = (0 if window is None
+          else np.minimum(np.maximum(q0 - window + 1, 0) // block_size, hi))
+    live = int(np.where(qe > 0, hi - lo + 1, 0).sum())
+    return live, int(Bb) * n_q * int(n_max)

@@ -15,9 +15,15 @@ snapshot every S seconds of stream time; `--trace-out f.json` records
 step-phase spans and writes a Chrome trace (load it at https://ui.perfetto.dev
 or chrome://tracing); `--metrics-out f.json` dumps the final metrics
 registry snapshot; `--jax-profile DIR` wraps the run in
-`jax.profiler.trace`. All loop timing -- arrivals, idle sleeps, the
-periodic snapshot cadence -- runs off the engine's single injectable clock
-(`engine.obs.now`), so `serve_stream` is deterministic under a fake clock.
+`jax.profiler.trace`, whose timeline shows the engine's phases beside the
+device ops: `engine.step` around `engine.schedule` (with `engine.alloc`
+inside), `engine.build`, the launch span (`engine.mixed`, or
+`engine.prefill` / `.decode` / `.draft` / `.verify`) with `engine.inputs`
+and `engine.launch` inside, `engine.sync` (`engine.wait`,
+`engine.readback`), `engine.account`, `engine.emit` and `engine.policy`.
+All loop timing -- arrivals, idle sleeps, the periodic snapshot cadence --
+runs off the engine's single injectable clock (`engine.obs.now`), so
+`serve_stream` is deterministic under a fake clock.
 """
 
 from __future__ import annotations

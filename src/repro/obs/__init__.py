@@ -13,8 +13,11 @@ counters:
 
 `Observability` bundles both behind a single injectable clock: every span it
 opens is timed into a per-phase duration histogram (always on -- a dict
-lookup and two float adds) and, when `ObsConfig.trace` is set, also recorded
-as a trace event. Compile events (a new entry appearing in a bucketed jit
+lookup and two float adds), enters a `jax.profiler.TraceAnnotation` named
+`engine.<phase>` with the span's args (about a microsecond with no profiler
+session; inside one, the phase lies on the device trace's clock beside the
+device ops), and, when `ObsConfig.trace` is set, is also recorded as a ring
+tracer event. Compile events (a new entry appearing in a bucketed jit
 cache) are logged with their bucket shape and wall time -- recompile storms
 are the canonical silent perf killer of fixed-shape serving, and this makes
 them visible in `stats()`, the metrics snapshot, and the trace.
@@ -28,6 +31,8 @@ import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .metrics import (Counter, DEFAULT_TIME_EDGES, Gauge, Histogram,
                       MetricsRegistry)
 from .tracing import NULL_TRACER, NullTracer, StepTracer
@@ -35,6 +40,8 @@ from .tracing import NULL_TRACER, NullTracer, StepTracer
 # per-phase duration edges (seconds): 10us .. 10s, ~x3 per bucket
 PHASE_EDGES = (1e-5, 3.16e-5, 1e-4, 3.16e-4, 1e-3, 3.16e-3, 1e-2, 3.16e-2,
                1e-1, 3.16e-1, 1.0, 3.16, 10.0)
+# profiler annotations of engine phases are named SPAN_PREFIX + phase
+SPAN_PREFIX = "engine."
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,10 +61,11 @@ class ObsConfig:
 
 
 class _ObsSpan:
-    """Times one engine phase: always observes the per-phase histogram,
+    """Times one engine phase: always observes the per-phase histogram and
+    annotates the profiler's timeline (`engine.<phase>`, with the args),
     and records a trace span when tracing is enabled."""
 
-    __slots__ = ("_obs", "name", "args", "t0")
+    __slots__ = ("_obs", "name", "args", "t0", "_ann")
 
     def __init__(self, obs: "Observability", name: str,
                  args: Optional[Dict[str, Any]]):
@@ -65,18 +73,28 @@ class _ObsSpan:
         self.name = name
         self.args = args
         self.t0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "_ObsSpan":
+        self._ann = TraceAnnotation(SPAN_PREFIX + self.name,
+                                    **(self.args or {}))
+        self._ann.__enter__()
         self.t0 = self._obs.now()
         return self
 
     def __exit__(self, *exc) -> None:
         obs = self._obs
         dt = obs.now() - self.t0
+        self._ann.__exit__(*exc)
         obs.phase_hist(self.name).observe(dt)
         if obs.tracer.enabled:
             obs.tracer._record(("X", self.name, "step", self.t0, dt,
                                 self.args))
+
+    def set(self, **args) -> None:
+        """Add args known only once the span is open (e.g. a step's kind)."""
+        self.args = {**(self.args or {}), **args}
+        self._ann.set_metadata(**args)
 
     @property
     def elapsed(self) -> float:
@@ -115,6 +133,13 @@ class Observability:
 
     def span(self, name: str, **args) -> _ObsSpan:
         return _ObsSpan(self, name, args or None)
+
+    @property
+    def recording(self) -> bool:
+        """Whether a span's args reach any reader (a profiler session or
+        the ring tracer): args that cost more than a lookup are computed
+        only then."""
+        return self.tracer.enabled or TraceAnnotation.is_enabled()
 
     # -- compile events -----------------------------------------------------
 

@@ -35,10 +35,18 @@ Sampling is inside the jitted step and keyed per request as
 fold_in(PRNGKey(seed), num_generated): a request's sample stream is
 deterministic regardless of how it was batched, bucketed, or preempted.
 
-Observability (src/repro/obs/): every step phase -- schedule, block alloc,
-prefill, decode, draft, verify, host<->device sync, emit, defrag -- runs
-inside an `obs.span(...)`, feeding per-phase duration histograms (always on)
-and, with `ObsConfig.trace`, a ring-buffered Chrome-trace exporter. The
+Observability (src/repro/obs/): every step phase runs inside an
+`obs.span(...)`, feeding per-phase duration histograms (always on), the
+profiler's timeline as `engine.<phase>` annotations (so `--jax-profile` lays
+each phase beside the device ops), and, with `ObsConfig.trace`, a
+ring-buffered Chrome-trace exporter. A step is `step` (args `step`, `kind`)
+around schedule (block alloc inside it), build (host batch arrays), the
+launch span -- mixed, prefill, decode, draft, verify, each holding `inputs`
+(host->device puts) and `launch` (the jitted call) where it does its own
+puts -- then sync (`wait` for the device, `readback` of the outputs),
+account (LAMP counts and per-row bookkeeping), emit and policy; `mixed`
+carries rows, bucket, tokens and, while recording, the kernel grid's
+kv_live / kv_walked. The
 engine's counters live in the obs metrics registry (`stats()` is a view over
 it; the legacy attribute names are properties over the same counters). LAMP
 recompute counts are threaded per layer: the jitted steps return (L, B)
@@ -61,6 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import grid_blocks
 from repro.models import transformer
 from repro.obs import ObsConfig, Observability
 from repro.obs.audit import AuditConfig, ShadowAuditor
@@ -731,6 +740,10 @@ class LampEngine:
     def step(self) -> List[RequestOutput]:
         """Run one engine step; returns requests finished by this step
         (successfully or -- with `RequestOutput.error` set -- failed)."""
+        with self.obs.span("step", step=self.total_steps) as sp:
+            return self._step(sp)
+
+    def _step(self, sp) -> List[RequestOutput]:
         if self._start is None:
             self._start = self._now()
         t0 = self._now()
@@ -740,9 +753,11 @@ class LampEngine:
             # injected stall: the step schedules nothing and reports the
             # configured latency spike, so the policy sees the pressure and
             # the run_to_completion watchdog sees no progress
+            sp.set(kind="stall")
             self._last_step_wall = inj.config.stall_s
             if self.policy is not None:
-                self._policy_update()
+                with self.obs.span("policy"):
+                    self._policy_update()
             return self._drain_failures()
         if self._has_deadlines:
             self._expire_deadlines()
@@ -756,6 +771,7 @@ class LampEngine:
         if d_alloc:
             self._last_alloc_degrades = self.scheduler.alloc_fault_degrades
             self._recover("alloc_defer", n=d_alloc)
+        sp.set(kind=plan.kind if plan is not None else "none")
         if plan is None:
             return self._drain_failures()
         # audit rows are *captured* before the sub-step runs (it mutates
@@ -824,7 +840,8 @@ class LampEngine:
             self.pool.check_invariants(self._seqs.values())
         self._last_step_wall = self._now() - t0
         if self.policy is not None:
-            self._policy_update()
+            with self.obs.span("policy"):
+                self._policy_update()
         return done
 
     def _policy_update(self) -> None:
@@ -945,6 +962,20 @@ class LampEngine:
             temps[i] = seq.sampling.temperature
             topks[i] = seq.sampling.top_k
         return bt, seeds, counts, temps, topks
+
+    @staticmethod
+    def _put(*arrays) -> List[Any]:
+        """Host batch arrays -> device arrays (the `inputs` phase)."""
+        return [jnp.asarray(a) for a in arrays]
+
+    def _sync(self, out, *arrays) -> tuple:
+        """Wait for a launch's outputs, then copy `arrays` (some of them)
+        to the host: the `sync` phase, split into `wait` and `readback`."""
+        with self.obs.span("sync"):
+            with self.obs.span("wait"):
+                jax.block_until_ready(out)
+            with self.obs.span("readback"):
+                return tuple(np.asarray(a) for a in arrays)
 
     def _account_lamp(self, seqs: List[Sequence], nsel: np.ndarray,
                       nval: np.ndarray, *, verify: bool = False,
@@ -1235,99 +1266,98 @@ class LampEngine:
         DECODE; otherwise it stays PREFILL with its cursor advanced."""
         Wb = _bucket(max(windows), 0)
         Bb = _bucket(len(seqs), self.econfig.max_prefill_batch)
-        tokens = np.zeros((Bb, Wb), np.int32)
-        starts = np.zeros((Bb,), np.int32)
-        lengths = np.ones((Bb,), np.int32)   # pad rows: 1 token in null block
-        for i, (seq, w) in enumerate(zip(seqs, windows)):
-            cur = seq.prefill_cursor
-            tokens[i, :w] = seq.prefill_tokens()[cur:cur + w]
-            starts[i] = cur
-            lengths[i] = w
-        bt, seeds, counts, temps, topks = self._batch_arrays(seqs, Bb)
+        with self.obs.span("build"):
+            tokens = np.zeros((Bb, Wb), np.int32)
+            starts = np.zeros((Bb,), np.int32)
+            lengths = np.ones((Bb,), np.int32)  # pad rows: 1 token, null block
+            for i, (seq, w) in enumerate(zip(seqs, windows)):
+                cur = seq.prefill_cursor
+                tokens[i, :w] = seq.prefill_tokens()[cur:cur + w]
+                starts[i] = cur
+                lengths[i] = w
+            bt, seeds, counts, temps, topks = self._batch_arrays(seqs, Bb)
         prefill_fn, _ = self._step_fns(seqs)
         n0 = _cache_size(prefill_fn)
         with self.obs.span("prefill", rows=len(seqs), bucket=[Bb, Wb],
                            tokens=int(sum(windows))) as sp:
-            out = prefill_fn(
-                self.params, self.pool.k, self.pool.v, jnp.asarray(tokens),
-                jnp.asarray(bt), jnp.asarray(starts), jnp.asarray(lengths),
-                jnp.asarray(self._taus), jnp.asarray(seeds),
-                jnp.asarray(counts), jnp.asarray(temps), jnp.asarray(topks))
+            with self.obs.span("inputs"):
+                args = self._put(tokens, bt, starts, lengths, self._taus,
+                                 seeds, counts, temps, topks)
+            with self.obs.span("launch"):
+                out = prefill_fn(self.params, self.pool.k, self.pool.v,
+                                 *args)
         self._c_launches["prefill"].inc()
-        with self.obs.span("sync"):
-            jax.block_until_ready(out)
-            nxt, health, self.pool.k, self.pool.v, nsel, nval = out
-            nxt, health, nsel, nval = (np.asarray(nxt), np.asarray(health),
-                                       np.asarray(nsel), np.asarray(nval))
+        nxt, health, self.pool.k, self.pool.v, nsel, nval = out
+        nxt, health, nsel, nval = self._sync(out, nxt, health, nsel, nval)
         if n0 >= 0 and _cache_size(prefill_fn) > n0:
             self.obs.record_compile("prefill", (Bb, Wb), sp.elapsed,
                                     self.total_steps)
-        health = self._inject_nan(
-            seqs, health,
-            [(s.prefill_cursor, w) for s, w in zip(seqs, windows)])
-        guard = self.econfig.health_guard
-        now = self._now()
-        self._account_lamp(seqs, nsel, nval)
-        for i, (seq, w) in enumerate(zip(seqs, windows)):
-            if guard and self._unhealthy(health[i]):
-                # skip ALL bookkeeping: cursor stays, the retry rewrites
-                # the same window over the same (possibly poisoned) blocks
-                self._quarantine.append((seq, "prefill", w))
-                continue
-            seq.prefill_cursor += w
-            seq.cache_len = seq.prefill_cursor
-            self._c_prefill_tokens.inc(w)
-            if self.econfig.prefix_cache:
-                # the window's full blocks now hold real KV: make them
-                # matchable by later arrivals (and by our own resume); the
-                # admission-time chain hashes avoid rehashing per chunk
-                self.pool.register_prefix(seq.prefill_tokens(),
-                                          seq.block_ids, seq.cache_len,
-                                          hashes=seq.prefix_hashes)
-            if seq.prefill_remaining == 0:
-                seq.status = SequenceStatus.DECODE
-                seq.on_token(int(nxt[i]), now)
-                self._c_generated.inc()
-            else:
-                self._c_prefill_chunks.inc()
+        with self.obs.span("account"):
+            health = self._inject_nan(
+                seqs, health,
+                [(s.prefill_cursor, w) for s, w in zip(seqs, windows)])
+            guard = self.econfig.health_guard
+            now = self._now()
+            self._account_lamp(seqs, nsel, nval)
+            for i, (seq, w) in enumerate(zip(seqs, windows)):
+                if guard and self._unhealthy(health[i]):
+                    # skip ALL bookkeeping: cursor stays, the retry rewrites
+                    # the same window over the same (possibly poisoned) blocks
+                    self._quarantine.append((seq, "prefill", w))
+                    continue
+                seq.prefill_cursor += w
+                seq.cache_len = seq.prefill_cursor
+                self._c_prefill_tokens.inc(w)
+                if self.econfig.prefix_cache:
+                    # the window's full blocks now hold real KV: make them
+                    # matchable by later arrivals (and by our own resume); the
+                    # admission-time chain hashes avoid rehashing per chunk
+                    self.pool.register_prefix(seq.prefill_tokens(),
+                                              seq.block_ids, seq.cache_len,
+                                              hashes=seq.prefix_hashes)
+                if seq.prefill_remaining == 0:
+                    seq.status = SequenceStatus.DECODE
+                    seq.on_token(int(nxt[i]), now)
+                    self._c_generated.inc()
+                else:
+                    self._c_prefill_chunks.inc()
 
     def _step_decode(self, seqs: List[Sequence]) -> None:
         Rb = _bucket(len(seqs), self.econfig.max_decode_batch)
-        tokens = np.zeros((Rb, 1), np.int32)
-        lengths = np.zeros((Rb,), np.int32)  # pad rows write into null block
-        for i, seq in enumerate(seqs):
-            tokens[i, 0] = seq.last_token
-            lengths[i] = seq.cache_len
-        bt, seeds, counts, temps, topks = self._batch_arrays(seqs, Rb)
+        with self.obs.span("build"):
+            tokens = np.zeros((Rb, 1), np.int32)
+            lengths = np.zeros((Rb,), np.int32)  # pad rows: the null block
+            for i, seq in enumerate(seqs):
+                tokens[i, 0] = seq.last_token
+                lengths[i] = seq.cache_len
+            bt, seeds, counts, temps, topks = self._batch_arrays(seqs, Rb)
         _, decode_fn = self._step_fns(seqs)
         n0 = _cache_size(decode_fn)
         with self.obs.span("decode", rows=len(seqs), bucket=[Rb]) as sp:
-            out = decode_fn(
-                self.params, self.pool.k, self.pool.v, jnp.asarray(bt),
-                jnp.asarray(lengths), jnp.asarray(tokens),
-                jnp.asarray(self._taus), jnp.asarray(seeds),
-                jnp.asarray(counts), jnp.asarray(temps), jnp.asarray(topks))
+            with self.obs.span("inputs"):
+                args = self._put(bt, lengths, tokens, self._taus, seeds,
+                                 counts, temps, topks)
+            with self.obs.span("launch"):
+                out = decode_fn(self.params, self.pool.k, self.pool.v, *args)
         self._c_launches["decode"].inc()
-        with self.obs.span("sync"):
-            jax.block_until_ready(out)
-            nxt, health, self.pool.k, self.pool.v, nsel, nval = out
-            nxt, health, nsel, nval = (np.asarray(nxt), np.asarray(health),
-                                       np.asarray(nsel), np.asarray(nval))
+        nxt, health, self.pool.k, self.pool.v, nsel, nval = out
+        nxt, health, nsel, nval = self._sync(out, nxt, health, nsel, nval)
         if n0 >= 0 and _cache_size(decode_fn) > n0:
             self.obs.record_compile("decode", (Rb,), sp.elapsed,
                                     self.total_steps)
-        health = self._inject_nan(seqs, health,
-                                  [(s.cache_len, 1) for s in seqs])
-        guard = self.econfig.health_guard
-        now = self._now()
-        self._account_lamp(seqs, nsel, nval)
-        for i, seq in enumerate(seqs):
-            if guard and self._unhealthy(health[i]):
-                self._quarantine.append((seq, "decode", 1))
-                continue
-            seq.cache_len += 1
-            seq.on_token(int(nxt[i]), now)
-            self._c_generated.inc()
+        with self.obs.span("account"):
+            health = self._inject_nan(seqs, health,
+                                      [(s.cache_len, 1) for s in seqs])
+            guard = self.econfig.health_guard
+            now = self._now()
+            self._account_lamp(seqs, nsel, nval)
+            for i, seq in enumerate(seqs):
+                if guard and self._unhealthy(health[i]):
+                    self._quarantine.append((seq, "decode", 1))
+                    continue
+                seq.cache_len += 1
+                seq.on_token(int(nxt[i]), now)
+                self._c_generated.inc()
 
     def _step_spec(self, seqs: List[Sequence],
                    draft_lens: List[int]) -> None:
@@ -1339,18 +1369,19 @@ class LampEngine:
         KV. A sequence with draft budget 0 runs a verify-only round, which
         is exactly one plain decode step's progress."""
         Rb = _bucket(len(seqs), self.econfig.max_decode_batch)
-        tok0 = np.zeros((Rb,), np.int32)
-        lengths = np.zeros((Rb,), np.int32)  # pad rows write into null block
-        kd = np.zeros((Rb,), np.int32)
-        for i, seq in enumerate(seqs):
-            tok0[i] = seq.last_token
-            lengths[i] = seq.cache_len
-            kd[i] = draft_lens[i]
-        bt, seeds, counts, temps, topks = self._batch_arrays(seqs, Rb)
-        bt, lengths, tok0, kd, seeds, counts, temps, topks = map(
-            jnp.asarray, (bt, lengths, tok0, kd, seeds, counts, temps,
-                          topks))
-        taus = jnp.asarray(self._taus)
+        with self.obs.span("build"):
+            tok0 = np.zeros((Rb,), np.int32)
+            lengths = np.zeros((Rb,), np.int32)  # pad rows: the null block
+            kd = np.zeros((Rb,), np.int32)
+            for i, seq in enumerate(seqs):
+                tok0[i] = seq.last_token
+                lengths[i] = seq.cache_len
+                kd[i] = draft_lens[i]
+            bt, seeds, counts, temps, topks = self._batch_arrays(seqs, Rb)
+        with self.obs.span("inputs"):
+            bt, lengths, tok0, kd, taus, seeds, counts, temps, topks = \
+                self._put(bt, lengths, tok0, kd, self._taus, seeds, counts,
+                          temps, topks)
         draft_fn, verify_fn = self._spec_fns(seqs)
         n0d, n0v = _cache_size(draft_fn), _cache_size(verify_fn)
         with self.obs.span("draft", rows=len(seqs), bucket=[Rb]) as spd:
@@ -1366,63 +1397,62 @@ class LampEngine:
                 d_logits, bt, lengths, kd, taus, seeds, counts, temps,
                 topks)
         self._c_launches["verify"].inc()
-        with self.obs.span("sync"):
-            jax.block_until_ready(out)
-            emit, n_acc, health, self.pool.k, self.pool.v, nsel, nval = out
-            emit, n_acc, health, nsel, nval = (
-                np.asarray(emit), np.asarray(n_acc), np.asarray(health),
-                np.asarray(nsel), np.asarray(nval))
+        emit, n_acc, health, self.pool.k, self.pool.v, nsel, nval = out
+        emit, n_acc, health, nsel, nval = self._sync(out, emit, n_acc, health,
+                                                     nsel, nval)
         if n0d >= 0 and _cache_size(draft_fn) > n0d:
             self.obs.record_compile("draft", (Rb,), spd.elapsed,
                                     self.total_steps)
         if n0v >= 0 and _cache_size(verify_fn) > n0v:
             self.obs.record_compile("verify", (Rb,), spv.elapsed,
                                     self.total_steps)
-        # poison width 1 even for drafted rows: the quarantine retries a
-        # verify row as a plain decode, which rewrites only position
-        # cache_len -- poison past it would outlive the recovery (the
-        # rolled-back draft positions can share the kept tail block, and
-        # the gather kernel streams the whole block span)
-        health = self._inject_nan(seqs, health,
-                                  [(s.cache_len, 1) for s in seqs])
-        guard = self.econfig.health_guard
-        now = self._now()
-        self._account_lamp(seqs, nsel, nval, verify=True)
-        for i, seq in enumerate(seqs):
-            if guard and self._unhealthy(health[i]):
-                # discard the whole round for this row (no drafted/accepted
-                # accounting), free the draft-span blocks -- keeping one
-                # slot past cache_len so the width-1 retry's write position
-                # stays covered -- and retry as a plain decode step
+        with self.obs.span("account"):
+            # poison width 1 even for drafted rows: the quarantine retries a
+            # verify row as a plain decode, which rewrites only position
+            # cache_len -- poison past it would outlive the recovery (the
+            # rolled-back draft positions can share the kept tail block, and
+            # the gather kernel streams the whole block span)
+            health = self._inject_nan(seqs, health,
+                                      [(s.cache_len, 1) for s in seqs])
+            guard = self.econfig.health_guard
+            now = self._now()
+            self._account_lamp(seqs, nsel, nval, verify=True)
+            for i, seq in enumerate(seqs):
+                if guard and self._unhealthy(health[i]):
+                    # discard the whole round for this row (no drafted/accepted
+                    # accounting), free the draft-span blocks -- keeping one
+                    # slot past cache_len so the width-1 retry's write position
+                    # stays covered -- and retry as a plain decode step
+                    seq.block_ids = self.pool.rollback(seq.block_ids,
+                                                       seq.cache_len + 1)
+                    self._quarantine.append((seq, "decode", 1))
+                    continue
+                a = int(n_acc[i])
+                seq.spec_drafted += int(draft_lens[i])
+                self._c_spec_drafted.inc(int(draft_lens[i]))
+                # emit accepted drafts + the verifier's token, stopping at the
+                # request's own limits (surplus accepted tokens are dropped and
+                # their cache rolls back with the rejected ones)
+                appended = 0
+                for t in emit[i, :a + 1]:
+                    seq.on_token(int(t), now)
+                    appended += 1
+                    self._c_generated.inc()
+                    if seq.should_stop():
+                        break
+                # acceptance accounting covers only drafts actually *kept*: a
+                # stop token (or token limit) inside the accepted prefix drops
+                # the surplus, and counting those would overstate the
+                # acceptance rate the policy/scheduler steer by. An early stop
+                # at position j < a keeps j+1 tokens, all of them drafts; a
+                # full emit keeps a drafts + the verifier's token.
+                kept_accepted = min(a, appended)
+                seq.spec_accepted += kept_accepted
+                self._c_spec_accepted.inc(kept_accepted)
+                seq.cache_len += appended
+                self._c_spec_emitted.inc(appended)
                 seq.block_ids = self.pool.rollback(seq.block_ids,
-                                                   seq.cache_len + 1)
-                self._quarantine.append((seq, "decode", 1))
-                continue
-            a = int(n_acc[i])
-            seq.spec_drafted += int(draft_lens[i])
-            self._c_spec_drafted.inc(int(draft_lens[i]))
-            # emit accepted drafts + the verifier's token, stopping at the
-            # request's own limits (surplus accepted tokens are dropped and
-            # their cache rolls back with the rejected ones)
-            appended = 0
-            for t in emit[i, :a + 1]:
-                seq.on_token(int(t), now)
-                appended += 1
-                self._c_generated.inc()
-                if seq.should_stop():
-                    break
-            # acceptance accounting covers only drafts actually *kept*: a
-            # stop token (or token limit) inside the accepted prefix drops
-            # the surplus, and counting those would overstate the
-            # acceptance rate the policy/scheduler steer by. An early stop
-            # at position j < a keeps j+1 tokens, all of them drafts; a
-            # full emit keeps a drafts + the verifier's token.
-            kept_accepted = min(a, appended)
-            seq.spec_accepted += kept_accepted
-            self._c_spec_accepted.inc(kept_accepted)
-            seq.cache_len += appended
-            self._c_spec_emitted.inc(appended)
-            seq.block_ids = self.pool.rollback(seq.block_ids, seq.cache_len)
+                                                   seq.cache_len)
 
     def _step_mixed(self, plan: StepPlan) -> None:
         """Run one mixed plan as a single fused launch: prefill windows,
@@ -1450,164 +1480,166 @@ class LampEngine:
         if spec_round:
             # the accept rule reads k+1 window positions per verify row
             Wb = max(Wb, self.spec_config.verify_width)
-        tokens = np.zeros((Bb, Wb), np.int32)
-        starts = np.zeros((Bb,), np.int32)
-        qlens = np.ones((Bb,), np.int32)   # pad rows: 1 token in null block
-        for i, seq in enumerate(seqs):
-            w = windows[i]
-            if roles[i] == "prefill":
-                cur = seq.prefill_cursor
-                tokens[i, :w] = seq.prefill_tokens()[cur:cur + w]
-                starts[i] = cur
-            else:
-                # decode/verify: the window is [last_token, drafts...] at
-                # the decode tail (drafts scatter in-jit after the scan)
-                tokens[i, 0] = seq.last_token
-                starts[i] = seq.cache_len
-            qlens[i] = w
-        bt, seeds, counts, temps, topks = self._batch_arrays(seqs, Bb)
-        taus = jnp.asarray(self._taus)
+        with self.obs.span("build"):
+            tokens = np.zeros((Bb, Wb), np.int32)
+            starts = np.zeros((Bb,), np.int32)
+            qlens = np.ones((Bb,), np.int32)   # pad rows: 1 token, null block
+            for i, seq in enumerate(seqs):
+                w = windows[i]
+                if roles[i] == "prefill":
+                    cur = seq.prefill_cursor
+                    tokens[i, :w] = seq.prefill_tokens()[cur:cur + w]
+                    starts[i] = cur
+                else:
+                    # decode/verify: the window is [last_token, drafts...] at
+                    # the decode tail (drafts scatter in-jit after the scan)
+                    tokens[i, 0] = seq.last_token
+                    starts[i] = seq.cache_len
+                qlens[i] = w
+            bt, seeds, counts, temps, topks = self._batch_arrays(seqs, Bb)
+        mixed_args = dict(rows=len(seqs), bucket=[Bb, Wb],
+                          tokens=int(sum(windows)))
+        if self.obs.recording:
+            # the paged kernels' KV grid: steps on live blocks / all steps
+            mixed_args["kv_live"], mixed_args["kv_walked"] = grid_blocks(
+                starts, qlens, len(seqs), Bb, self.blocks_per_seq,
+                self.econfig.block_size, window=self.cfg.window)
         emit = n_acc = None
         if not spec_round:
             mixed_fn, _ = self._step_fns(seqs)
             n0 = _cache_size(mixed_fn)
-            with self.obs.span("mixed", rows=len(seqs), bucket=[Bb, Wb],
-                               tokens=int(sum(windows))) as sp:
-                out = mixed_fn(
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(tokens), jnp.asarray(bt),
-                    jnp.asarray(starts), jnp.asarray(qlens), taus,
-                    jnp.asarray(seeds), jnp.asarray(counts),
-                    jnp.asarray(temps), jnp.asarray(topks))
+            with self.obs.span("mixed", **mixed_args) as sp:
+                with self.obs.span("inputs"):
+                    args = self._put(tokens, bt, starts, qlens, self._taus,
+                                     seeds, counts, temps, topks)
+                with self.obs.span("launch"):
+                    out = mixed_fn(self.params, self.pool.k, self.pool.v,
+                                   *args)
             self._c_launches["mixed"].inc()
-            with self.obs.span("sync"):
-                jax.block_until_ready(out)
-                nxt, health, self.pool.k, self.pool.v, nsel, nval = out
-                nxt, health, nsel, nval = (
-                    np.asarray(nxt), np.asarray(health), np.asarray(nsel),
-                    np.asarray(nval))
+            nxt, health, self.pool.k, self.pool.v, nsel, nval = out
+            nxt, health, nsel, nval = self._sync(out, nxt, health, nsel,
+                                                 nval)
         else:
             dseqs = [seqs[i] for i in dec_rows]
             Rb = _bucket(len(dseqs), self.econfig.max_decode_batch)
-            tok0 = np.zeros((Rb,), np.int32)
-            dlens = np.zeros((Rb,), np.int32)
-            kdv = np.zeros((Rb,), np.int32)
-            for j, i in enumerate(dec_rows):
-                tok0[j] = seqs[i].last_token
-                dlens[j] = seqs[i].cache_len
-                kdv[j] = draft_lens[i]
-            dbt, dseeds, dcounts, dtemps, dtopks = self._batch_arrays(
-                dseqs, Rb)
+            with self.obs.span("build"):
+                tok0 = np.zeros((Rb,), np.int32)
+                dlens = np.zeros((Rb,), np.int32)
+                kdv = np.zeros((Rb,), np.int32)
+                for j, i in enumerate(dec_rows):
+                    tok0[j] = seqs[i].last_token
+                    dlens[j] = seqs[i].cache_len
+                    kdv[j] = draft_lens[i]
+                dbt, dseeds, dcounts, dtemps, dtopks = self._batch_arrays(
+                    dseqs, Rb)
+                # draft-row -> mixed-row scatter map; pad draft rows point
+                # out of range, which scatter mode="drop" discards
+                dec_pos = np.full((Rb,), Bb, np.int32)
+                dec_pos[:len(dec_rows)] = dec_rows
+                kd_full = np.zeros((Bb,), np.int32)
+                for i in dec_rows:
+                    kd_full[i] = draft_lens[i]
             draft_fn, _ = self._spec_fns(dseqs)
             n0d = _cache_size(draft_fn)
             with self.obs.span("draft", rows=len(dseqs),
                                bucket=[Rb]) as spd:
-                d_toks, d_logits, self.pool.k, self.pool.v = draft_fn(
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(dbt), jnp.asarray(dlens),
-                    jnp.asarray(tok0), jnp.asarray(kdv), taus,
-                    jnp.asarray(dseeds), jnp.asarray(dcounts),
-                    jnp.asarray(dtemps), jnp.asarray(dtopks))
+                with self.obs.span("inputs"):
+                    dargs = self._put(dbt, dlens, tok0, kdv)
+                    taus = jnp.asarray(self._taus)
+                    sargs = self._put(dseeds, dcounts, dtemps, dtopks)
+                with self.obs.span("launch"):
+                    d_toks, d_logits, self.pool.k, self.pool.v = draft_fn(
+                        self.params, self.pool.k, self.pool.v, *dargs, taus,
+                        *sargs)
             self._c_launches["draft"].inc()
             if n0d >= 0 and _cache_size(draft_fn) > n0d:
                 self.obs.record_compile("draft", (Rb,), spd.elapsed,
                                         self.total_steps)
             d_toks = self._inject_draft(dseqs, kdv, d_toks,
                                         d_logits.shape[-1])
-            # draft-row -> mixed-row scatter map; pad draft rows point out
-            # of range, which scatter mode="drop" discards
-            dec_pos = np.full((Rb,), Bb, np.int32)
-            dec_pos[:len(dec_rows)] = dec_rows
-            kd_full = np.zeros((Bb,), np.int32)
-            for i in dec_rows:
-                kd_full[i] = draft_lens[i]
             mixed_fn = _mixed_spec_step(
                 self._serving_cfg(), self.econfig.use_lamp,
                 self.econfig.kernel, self.spec_config,
                 any(s.sampling.top_k > 0 for s in seqs))
             n0 = _cache_size(mixed_fn)
-            with self.obs.span("mixed", rows=len(seqs), bucket=[Bb, Wb],
-                               tokens=int(sum(windows))) as sp:
-                out = mixed_fn(
-                    self.params, self.pool.k, self.pool.v,
-                    jnp.asarray(tokens), jnp.asarray(bt),
-                    jnp.asarray(starts), jnp.asarray(qlens),
-                    jnp.asarray(kd_full), jnp.asarray(dec_pos), d_toks,
-                    d_logits, taus, jnp.asarray(seeds),
-                    jnp.asarray(counts), jnp.asarray(temps),
-                    jnp.asarray(topks))
+            with self.obs.span("mixed", **mixed_args) as sp:
+                with self.obs.span("inputs"):
+                    args = self._put(tokens, bt, starts, qlens, kd_full,
+                                     dec_pos)
+                    sargs = self._put(seeds, counts, temps, topks)
+                with self.obs.span("launch"):
+                    out = mixed_fn(self.params, self.pool.k, self.pool.v,
+                                   *args, d_toks, d_logits, taus, *sargs)
             self._c_launches["mixed"].inc()
-            with self.obs.span("sync"):
-                jax.block_until_ready(out)
-                (nxt, emit, n_acc, health, self.pool.k, self.pool.v, nsel,
-                 nval) = out
-                nxt, emit, n_acc, health, nsel, nval = (
-                    np.asarray(nxt), np.asarray(emit), np.asarray(n_acc),
-                    np.asarray(health), np.asarray(nsel), np.asarray(nval))
+            (nxt, emit, n_acc, health, self.pool.k, self.pool.v, nsel,
+             nval) = out
+            nxt, emit, n_acc, health, nsel, nval = self._sync(
+                out, nxt, emit, n_acc, health, nsel, nval)
         if n0 >= 0 and _cache_size(mixed_fn) > n0:
             self.obs.record_compile("mixed", (Bb, Wb), sp.elapsed,
                                     self.total_steps)
-        # decode/verify rows poison width 1 (what the decode retry
-        # rewrites -- see _step_spec); prefill rows poison their window
-        health = self._inject_nan(
-            seqs, health,
-            [(s.prefill_cursor, windows[i]) if roles[i] == "prefill"
-             else (s.cache_len, 1) for i, s in enumerate(seqs)])
-        guard = self.econfig.health_guard
-        now = self._now()
-        self._account_lamp(seqs, nsel, nval,
-                           verify_cols=dec_rows if spec_round else None)
-        for i, seq in enumerate(seqs):
-            w = windows[i]
-            if guard and self._unhealthy(health[i]):
+        with self.obs.span("account"):
+            # decode/verify rows poison width 1 (what the decode retry
+            # rewrites -- see _step_spec); prefill rows poison their window
+            health = self._inject_nan(
+                seqs, health,
+                [(s.prefill_cursor, windows[i]) if roles[i] == "prefill"
+                 else (s.cache_len, 1) for i, s in enumerate(seqs)])
+            guard = self.econfig.health_guard
+            now = self._now()
+            self._account_lamp(seqs, nsel, nval,
+                               verify_cols=dec_rows if spec_round else None)
+            for i, seq in enumerate(seqs):
+                w = windows[i]
+                if guard and self._unhealthy(health[i]):
+                    if roles[i] == "prefill":
+                        self._quarantine.append((seq, "prefill", w))
+                    else:
+                        # discard this row's speculative round (if any), keep
+                        # one slot past cache_len for the width-1 retry's write
+                        # position, and retry it as a plain decode step
+                        seq.block_ids = self.pool.rollback(seq.block_ids,
+                                                           seq.cache_len + 1)
+                        self._quarantine.append((seq, "decode", 1))
+                    continue
                 if roles[i] == "prefill":
-                    self._quarantine.append((seq, "prefill", w))
-                else:
-                    # discard this row's speculative round (if any), keep
-                    # one slot past cache_len for the width-1 retry's write
-                    # position, and retry it as a plain decode step
+                    seq.prefill_cursor += w
+                    seq.cache_len = seq.prefill_cursor
+                    self._c_prefill_tokens.inc(w)
+                    if self.econfig.prefix_cache:
+                        self.pool.register_prefix(seq.prefill_tokens(),
+                                                  seq.block_ids, seq.cache_len,
+                                                  hashes=seq.prefix_hashes)
+                    if seq.prefill_remaining == 0:
+                        seq.status = SequenceStatus.DECODE
+                        seq.on_token(int(nxt[i]), now)
+                        self._c_generated.inc()
+                    else:
+                        self._c_prefill_chunks.inc()
+                elif spec_round:
+                    # identical bookkeeping to _step_spec (see the acceptance
+                    # accounting rationale there)
+                    a = int(n_acc[i])
+                    seq.spec_drafted += int(draft_lens[i])
+                    self._c_spec_drafted.inc(int(draft_lens[i]))
+                    appended = 0
+                    for t in emit[i, :a + 1]:
+                        seq.on_token(int(t), now)
+                        appended += 1
+                        self._c_generated.inc()
+                        if seq.should_stop():
+                            break
+                    kept_accepted = min(a, appended)
+                    seq.spec_accepted += kept_accepted
+                    self._c_spec_accepted.inc(kept_accepted)
+                    seq.cache_len += appended
+                    self._c_spec_emitted.inc(appended)
                     seq.block_ids = self.pool.rollback(seq.block_ids,
-                                                       seq.cache_len + 1)
-                    self._quarantine.append((seq, "decode", 1))
-                continue
-            if roles[i] == "prefill":
-                seq.prefill_cursor += w
-                seq.cache_len = seq.prefill_cursor
-                self._c_prefill_tokens.inc(w)
-                if self.econfig.prefix_cache:
-                    self.pool.register_prefix(seq.prefill_tokens(),
-                                              seq.block_ids, seq.cache_len,
-                                              hashes=seq.prefix_hashes)
-                if seq.prefill_remaining == 0:
-                    seq.status = SequenceStatus.DECODE
+                                                       seq.cache_len)
+                else:
+                    seq.cache_len += 1
                     seq.on_token(int(nxt[i]), now)
                     self._c_generated.inc()
-                else:
-                    self._c_prefill_chunks.inc()
-            elif spec_round:
-                # identical bookkeeping to _step_spec (see the acceptance
-                # accounting rationale there)
-                a = int(n_acc[i])
-                seq.spec_drafted += int(draft_lens[i])
-                self._c_spec_drafted.inc(int(draft_lens[i]))
-                appended = 0
-                for t in emit[i, :a + 1]:
-                    seq.on_token(int(t), now)
-                    appended += 1
-                    self._c_generated.inc()
-                    if seq.should_stop():
-                        break
-                kept_accepted = min(a, appended)
-                seq.spec_accepted += kept_accepted
-                self._c_spec_accepted.inc(kept_accepted)
-                seq.cache_len += appended
-                self._c_spec_emitted.inc(appended)
-                seq.block_ids = self.pool.rollback(seq.block_ids,
-                                                   seq.cache_len)
-            else:
-                seq.cache_len += 1
-                seq.on_token(int(nxt[i]), now)
-                self._c_generated.inc()
 
     def _step_mixed_split(self, plan: StepPlan) -> None:
         """Execute a mixed plan through the legacy phase-segregated

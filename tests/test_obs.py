@@ -337,6 +337,68 @@ def test_obs_write_trace_requires_path():
         obs.write_trace()
 
 
+class FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs (name, args) as
+    each annotation opens, its metadata as it is set, and its closing."""
+    log = []
+    enabled = False
+
+    def __init__(self, name, **args):
+        self.name, self.args = name, args
+
+    def __enter__(self):
+        self.log.append(("enter", self.name, dict(self.args)))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, None))
+
+    def set_metadata(self, **args):
+        self.log.append(("set", self.name, args))
+
+    @classmethod
+    def is_enabled(cls):
+        return cls.enabled
+
+
+@pytest.fixture
+def fake_annotation(monkeypatch):
+    import repro.obs as obs_mod
+    FakeAnnotation.log, FakeAnnotation.enabled = [], False
+    monkeypatch.setattr(obs_mod, "TraceAnnotation", FakeAnnotation)
+    return FakeAnnotation
+
+
+def test_obs_span_opens_trace_annotation(fake_annotation):
+    clk = FakeClock()
+    obs = Observability(ObsConfig(trace=False), clock=clk)
+    with obs.span("step", step=7) as outer:
+        with obs.span("sync"):
+            with obs.span("wait"):
+                clk.advance(0.25)
+            clk.advance(0.5)
+        outer.set(kind="mixed")
+        clk.advance(1.0)
+    assert fake_annotation.log == [
+        ("enter", "engine.step", {"step": 7}),
+        ("enter", "engine.sync", {}),
+        ("enter", "engine.wait", {}),
+        ("exit", "engine.wait", None),
+        ("exit", "engine.sync", None),
+        ("set", "engine.step", {"kind": "mixed"}),
+        ("exit", "engine.step", None)]
+    # nested spans keep their own histograms: each sees its whole extent
+    assert obs.phase_hist("wait").sum == pytest.approx(0.25)
+    assert obs.phase_hist("sync").sum == pytest.approx(0.75)
+    assert obs.phase_hist("step").sum == pytest.approx(1.75)
+    assert outer.args == {"step": 7, "kind": "mixed"}
+    # span args that cost more than a lookup wait for a reader
+    assert not obs.recording
+    fake_annotation.enabled = True
+    assert obs.recording
+    assert Observability(ObsConfig(trace=True)).recording
+
+
 # --------------------------------------------------------- engine integration
 
 @pytest.fixture(scope="module")
@@ -403,6 +465,81 @@ def test_engine_trace_on_token_identity_and_stats_compat(model):
         assert s["live_requests"] == 0
     assert eng_off.obs.tracer.events() == []
     assert len(eng_on.obs.tracer.events()) > 0
+
+
+def test_engine_phase_spans_on_the_profiler(model, fake_annotation):
+    """Every engine phase opens an `engine.*` annotation inside the step's
+    root span; while a profiler records, the fused launch span carries its
+    rows, bucket, tokens and kernel grid counts."""
+    fake_annotation.enabled = True
+    cfg, params = model
+    eng, _ = _run(cfg, params)
+    log = fake_annotation.log
+    names = {n for _, n, _ in log}
+    assert all(n.startswith("engine.") for n in names)
+    assert {"engine.step", "engine.schedule", "engine.build", "engine.mixed",
+            "engine.inputs", "engine.launch", "engine.sync", "engine.wait",
+            "engine.readback", "engine.account", "engine.emit"} <= names
+    depth = 0
+    for kind, name, _ in log:
+        if kind == "enter":
+            assert (depth == 0) == (name == "engine.step")
+            depth += 1
+        elif kind == "exit":
+            depth -= 1
+    assert depth == 0
+    steps = [a for k, n, a in log if k == "enter" and n == "engine.step"]
+    assert [a["step"] for a in steps] == list(range(eng.total_steps))
+    kinds = [a["kind"] for k, n, a in log if k == "set"]
+    assert kinds == ["mixed"] * eng.total_steps
+    mixed = [a for k, n, a in log if k == "enter" and n == "engine.mixed"]
+    assert len(mixed) == eng.mixed_steps
+    for a in mixed:
+        rows, width = a["bucket"]
+        assert a["rows"] <= rows and a["tokens"] <= rows * width
+        assert 0 < a["kv_live"] <= a["kv_walked"]
+        assert a["kv_walked"] == rows * eng.blocks_per_seq
+
+
+def test_engine_phase_extents_fake_clock(model, fake_annotation):
+    """schedule, emit and defrag time exactly the work they timed before
+    the new phases (the benchmark's host_ms_per_step reads these three):
+    the clock moves only inside the scheduler, the finished-request
+    collection, the pool's defrag, the batch build and the LAMP
+    accounting, each by its own amount."""
+    cfg, params = model
+    clk = FakeClock()
+    eng = LampEngine(cfg, params, EngineConfig(
+        block_size=4, n_blocks=64, max_model_len=64), clock=clk)
+
+    def ticking(fn, dt):
+        def wrapped(*a, **k):
+            clk.advance(dt)
+            return fn(*a, **k)
+        return wrapped
+
+    eng.scheduler.schedule = ticking(eng.scheduler.schedule, 1.0)
+    eng._collect_finished = ticking(eng._collect_finished, 2.0)
+    eng.pool.defrag = ticking(eng.pool.defrag, 4.0)
+    eng._batch_arrays = ticking(eng._batch_arrays, 8.0)
+    eng._account_lamp = ticking(eng._account_lamp, 16.0)
+    eng.add_request(list(range(8)), SamplingParams(max_new_tokens=3))
+    eng.add_request(list(range(5)), SamplingParams(max_new_tokens=2))
+    n = 0
+    while eng.has_unfinished():
+        eng.step()
+        n += 1
+    eng.defrag()
+    h = eng.obs.phase_hist
+    assert n == eng.total_steps == h("step").count == 3
+    assert h("schedule").sum == pytest.approx(1.0 * n)
+    assert h("emit").sum == pytest.approx(2.0 * n)
+    assert h("defrag").sum == pytest.approx(4.0)
+    assert h("build").sum == pytest.approx(8.0 * n)
+    assert h("account").sum == pytest.approx(16.0 * n)
+    assert h("step").sum == pytest.approx(27.0 * n)
+    for phase in ("inputs", "launch", "wait", "readback", "sync", "mixed"):
+        assert h(phase).count == n and h(phase).sum == 0.0
 
 
 def test_engine_compile_events_and_phase_histograms(model):

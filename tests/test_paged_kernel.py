@@ -30,7 +30,8 @@ from repro.core.attention import (attention_lamp, attention_reference,
                                   decode_attention_lamp)
 from repro.core.policy import LampSite
 from repro.kernels import ops
-from repro.kernels.paged_attention import decode_kv_bytes, supports_site
+from repro.kernels.paged_attention import (decode_kv_bytes, grid_blocks,
+                                           supports_site)
 from repro.models.layers import require_kernel_site
 
 H, HKV, HD = 4, 2, 16
@@ -297,6 +298,76 @@ def test_decode_kv_bytes_model():
     _, f3 = decode_kv_bytes([32, 32], n_max=4, block_size=8,
                             bytes_per_token=64, lamp=True)
     assert f3 == g2 * 3 // 2  # + one K stream for the smax pass
+
+
+def _grid_brute(starts, qlens, rows, Bb, n_max, bs, block_q, window, width):
+    """Walk one head of one pass of the prefill grid step by step. A step
+    is live when kv_map's clamp leaves its block index where it is, for a
+    real row and a tile that holds live queries; the kernel's own `live`
+    predicate (_pre_mask) must say the same."""
+    wq = width if block_q is None else min(block_q, width)
+    n_q = width // wq
+    live = walked = 0
+    for b in range(Bb):
+        for t in range(n_q):
+            for j in range(n_max):
+                walked += 1
+                if b >= rows:
+                    continue
+                q0 = starts[b] + t * wq
+                qe = min(max(qlens[b] - t * wq, 0), wq)
+                qm = min(max(qlens[b] - t * wq, 1), wq)      # as in kv_map
+                hi = min((q0 + qm - 1) // bs, n_max - 1)
+                lo = 0 if window is None else min(
+                    max(q0 - window + 1, 0) // bs, hi)
+                clamped = min(max(j, lo), hi)
+                on_live = qe > 0 and clamped == j
+                pre_live = qe > 0 and j * bs <= q0 + qe - 1 and (
+                    window is None or (j + 1) * bs - 1 > q0 - window)
+                assert on_live == pre_live, (b, t, j)
+                live += on_live
+    return live, walked
+
+
+GRID_CASES = {
+    # (starts, qlens, rows, Bb, n_max, bs, block_q, window, width)
+    "decode": ([5, 16, 27, 40], [1, 1, 1, 1], 4, 4, 8, 8, None, None, 1),
+    "mixed": ([0, 30, 7], [16, 1, 9], 3, 4, 8, 8, None, None, 16),
+    "pad-rows": ([3], [1], 1, 8, 8, 8, None, None, 1),
+    "last-block": ([63, 56], [1, 8], 2, 2, 8, 8, None, None, 8),
+    "window": ([20, 50, 3], [1, 8, 4], 3, 4, 8, 8, None, 12, 8),
+    "block-q": ([0, 10, 33], [16, 3, 9], 3, 4, 8, 8, 8, None, 16),
+    "block-q-window": ([0, 10, 33], [16, 3, 9], 3, 4, 8, 8, 4, 10, 16),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GRID_CASES))
+def test_grid_blocks_brute_force(case):
+    starts, qlens, rows, Bb, n_max, bs, block_q, window, width = \
+        GRID_CASES[case]
+    # the engine's arrays are Bb long; pad entries must not count
+    pad = Bb - rows
+    got = grid_blocks(starts + [0] * pad, qlens + [1] * pad, rows, Bb, n_max,
+                      bs, block_q=block_q, window=window,
+                      width=width if block_q else None)
+    want = _grid_brute(starts, qlens, rows, Bb, n_max, bs, block_q, window,
+                       width)
+    assert got == want
+    assert 0 < got[0] <= got[1]
+
+
+def test_grid_blocks_decode_matches_kv_traffic():
+    """A decode launch's live grid steps are the live blocks the traffic
+    model counts (fused bytes = live blocks x (2K + V))."""
+    lengths, n_max, bs = [5, 16, 27, 64], 8, 8
+    live, walked = grid_blocks([n - 1 for n in lengths], [1] * 4, 4, 4,
+                               n_max, bs)
+    _, fused = decode_kv_bytes(lengths, n_max=n_max, block_size=bs,
+                               bytes_per_token=1, lamp=True)
+    assert live * bs * 3 == fused
+    assert walked == 4 * n_max
+    with pytest.raises(ValueError, match="width"):
+        grid_blocks([0], [4], 1, 1, n_max, bs, block_q=4)
 
 
 # ------------------------------------------------------------ fuzz harness

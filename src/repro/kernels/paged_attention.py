@@ -18,14 +18,36 @@ disappears. The index map clamps j into the row's *live* range [lo, hi]
 (past-the-length blocks, and blocks wholly outside the sliding window,
 re-map to an already-resident live block, costing no fresh DMA) and the
 block's compute is guarded with ``pl.when`` -- fully-masked blocks are
-skipped, not summed as zeros. GQA is resolved in the index map as well
-(query head -> kv head), so K/V are never repeated in memory.
+skipped, not summed as zeros.
+
+Head fold: one grid step walks one KV block for a group of hb KV heads.
+Causal masks, sliding windows and per-row query counts never read the
+head, so every head of a row walks the same blocks with the same liveness,
+and a grid step costs a fixed time whatever it holds. The head-major arena
+makes a block's hb heads one contiguous (1, hb, block_size, hd) slab, one
+DMA, indexed (block_tables[row, j], group, 0, 0). Queries keep their
+(B, H, W, hd) layout, blocked (1, hb * rep, wq, hd): the rep query heads of
+a KV head are adjacent (kv head = query head // rep), so one DMA of a
+group's K/V serves all its query heads and GQA never repeats K/V in HBM.
+The per-tile helpers take the folded heads as a leading batch axis: one
+head-batched ``dot_general`` per product and one vector op per step of the
+softmax, for all heads at once. At GPT-2's decode buckets on a v5e a
+rolled ``fori_loop`` over the heads kept 7-30% of the batched body's gain
+and a static loop 66-75%; the static loop's tracing also grew with the
+heads, about 5 s more set-up per bucket on GPT-2 XL.
+
+hb (``kv_heads_per_step``) follows the shape alone: the largest divisor of
+Hkv whose grid step (``grid_step_vmem_bytes``: double-buffered q, out, K, V
+and row tiles, the scratch, and every folded head's logits temporaries,
+padded to (8, 128) tiles) fits ``VMEM_BUDGET``, three quarters of the
+16 MiB of scoped VMEM Mosaic grants a kernel on a v5e. A decode tile
+(wq = 1) folds every head of GPT-2 (12, 25); the 1024-wide prefill tile
+falls to hb = 1, where one wide tile already amortises its grid step.
 
 Mosaic tiling: every BlockSpec's last two dimensions are whole or tile
-multiples. A KV block is (1, 1, block_size, hd) of the head-major arena,
-indexed (block, kv_head, 0, 0); per-row statistics and selection counts
-carry a trailing unit axis ((rows, W, 1) blocked (1, wq, 1)), and the
-running softmax state lives in (wq, 1) VMEM scratch.
+multiples. Per-row statistics and selection counts carry a trailing unit
+axis ((B, H, W, 1) blocked (1, hb * rep, wq, 1)), and the running softmax
+state lives in (hb * rep, wq, 1) / (hb * rep, wq, hd) VMEM scratch.
 
 Precision: every product here names Precision.HIGHEST. The selective
 recompute and the softmax @ V product are FP32 as LAMP defines them, and
@@ -65,9 +87,9 @@ max-based rules (relaxed / relaxed_ln).
 
 Variants:
   paged_decode_attention  -- one query row per sequence at absolute
-      position lengths[r] - 1; grid (R*H, n_max), like ``flash_decode``.
+      position lengths[r] - 1; grid (R * Hkv/hb, n_max).
   paged_prefill_attention -- windowed prefill: query tile x block grid
-      ((B*H, W/block_q, n_max)) with absolute-position causal masks
+      ((B * Hkv/hb, W/block_q, n_max)) with absolute-position causal masks
       (query row w of sequence b sits at position starts[b] + w). With the
       optional per-row ``qlens`` scalar-prefetch operand this is also the
       *mixed-row* grid: each row carries its own live query count, so one
@@ -115,14 +137,19 @@ def supports_site(site: LampSite) -> bool:
 
 
 def _qk(q, k):
-    """FP32 q @ k^T: (rows, hd) x (keys, hd) -> (rows, keys)."""
-    return jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=_HI,
+    """FP32 q @ k^T per head: (heads, rows, hd) x (heads, keys, hd) ->
+    (heads, rows, keys)."""
+    return jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                               precision=_HI,
                                preferred_element_type=jnp.float32)
 
 
 def _pv(p, v):
-    """FP32 softmax-weights @ V: (rows, keys) x (keys, hd) -> (rows, hd)."""
-    return jax.lax.dot(p, v, precision=_HI, preferred_element_type=jnp.float32)
+    """FP32 softmax-weights @ V per head: (heads, rows, keys) x
+    (heads, keys, hd) -> (heads, rows, hd)."""
+    return jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
+                               precision=_HI,
+                               preferred_element_type=jnp.float32)
 
 
 def _y_low(q, k, mu: int, granularity: int):
@@ -135,9 +162,9 @@ def _y_low(q, k, mu: int, granularity: int):
     if granularity == 0 or granularity >= D:
         return round_to_mantissa(_qk(q, k), mu)
     g = int(granularity)
-    acc = jnp.zeros((q.shape[0], k.shape[0]), jnp.float32)
+    acc = jnp.zeros(q.shape[:2] + k.shape[1:2], jnp.float32)
     for s in range(-(-D // g)):
-        part = _qk(q[:, s * g:(s + 1) * g], k[:, s * g:(s + 1) * g])
+        part = _qk(q[..., s * g:(s + 1) * g], k[..., s * g:(s + 1) * g])
         acc = round_to_mantissa(acc + part, mu)
     return acc
 
@@ -175,8 +202,9 @@ def _rowsum(x):
 
 
 def _stats_block(y, ok, smax_ref, m_ref, l_ref):
-    """Pass-1 accumulation of one logits tile into the (rows, 1) running
-    row statistics: smax = max(y + log|y|), m = max y, l = sum exp(y - m)."""
+    """Pass-1 accumulation of one (heads, rows, keys) logits tile into the
+    (heads, rows, 1) running row statistics: smax = max(y + log|y|),
+    m = max y, l = sum exp(y - m)."""
     s = jnp.where(ok, y + jnp.log(jnp.abs(y)), _NEG)
     smax_ref[...] = jnp.maximum(smax_ref[...], _rowmax(s))
     m_new = jnp.maximum(m_ref[...], _rowmax(jnp.where(ok, y, _NEG)))
@@ -186,8 +214,8 @@ def _stats_block(y, ok, smax_ref, m_ref, l_ref):
 
 
 def _softmax_block(y, ok, v, acc_ref, m_ref, l_ref):
-    """Pass-2 online-softmax accumulation of one tile into (rows, hd) acc
-    and (rows, 1) m / l."""
+    """Pass-2 online-softmax accumulation of one tile into (heads, rows, hd)
+    acc and (heads, rows, 1) m / l."""
     y = jnp.where(ok, y, _NEG)
     m_prev = m_ref[...]
     m_new = jnp.maximum(m_prev, _rowmax(y))
@@ -215,7 +243,67 @@ def _lamp_logits(q, k, ok, smax, m_low, l_low, n_row, cnt_ref, *, lamp, mu,
 
 
 # ---------------------------------------------------------------------------
-# Decode variant: one query row per sequence, grid (R*H, n_max)
+# Head fold: one grid step walks one KV block for a group of hb KV heads
+# ---------------------------------------------------------------------------
+
+# VMEM one grid step may fill. Mosaic grants a kernel 16 MiB of scoped VMEM
+# by default on a v5e; the fold keeps to three quarters of it, leaving the
+# rest to Mosaic's internal scratch and the body's smaller temporaries.
+VMEM_BUDGET = 12 * 2 ** 20
+# (rows, block_size) float32 temporaries a folded body holds at once: the
+# look-ahead logits, the FP32 logits, the softmax weights and the mask
+_LOGIT_TILES = 4
+
+
+def _tile_bytes(rows: int, cols: int) -> int:
+    """Bytes of a float32 (rows, cols) array in VMEM, padded to (8, 128)
+    tiles."""
+    return -(-rows // 8) * 8 * (-(-cols // 128) * 128) * 4
+
+
+def grid_step_vmem_bytes(hb: int, rep: int, wq: int, block_size: int,
+                         head_dim: int) -> int:
+    """VMEM of one grid step of the recompute pass (the larger of the two)
+    folding hb KV heads (hb * rep query heads) over a wq-row query tile:
+    the double-buffered q, out, K, V, row-statistics and count tiles, the
+    scratch, and the logits temporaries of every folded head."""
+    n = hb * rep
+    q = n * _tile_bytes(wq, head_dim)
+    row = n * _tile_bytes(wq, 1)
+    kv = hb * _tile_bytes(block_size, head_dim)
+    return (2 * (2 * q + 2 * kv + 4 * row)      # q, out, K, V, 3 stats, nsel
+            + q + 3 * row                       # scratch: acc, m, l, counts
+            + _LOGIT_TILES * n * _tile_bytes(wq, block_size))
+
+
+def kv_heads_per_step(n_kv_heads: int, rep: int, wq: int, block_size: int,
+                      head_dim: int) -> int:
+    """hb: the largest divisor of n_kv_heads whose grid step fits
+    VMEM_BUDGET, else 1. A grid step costs a fixed time whatever it holds,
+    and causal masks, windows and per-row query counts never read the head,
+    so every head of a row walks the same blocks: folding them divides the
+    grid by hb at no change in the arithmetic. Narrow query tiles (decode)
+    fold every head; the widest prefill tiles may fall to hb = 1, where one
+    wide tile already amortises its grid step."""
+    return max([d for d in range(1, n_kv_heads + 1) if n_kv_heads % d == 0
+                and grid_step_vmem_bytes(d, rep, wq, block_size, head_dim)
+                <= VMEM_BUDGET], default=1)
+
+
+def _per_query_head(ref, rep: int):
+    """A grid step's K or V tile, (1, hb, bs, hd), as one (bs, hd) tile per
+    folded query head: (hb * rep, bs, hd), each KV head repeated for its
+    rep query heads in VMEM (the DMA moved it once)."""
+    x = ref[0].astype(jnp.float32)
+    if rep == 1:
+        return x
+    hb, bs, hd = x.shape
+    return jnp.broadcast_to(x[:, None], (hb, rep, bs, hd)).reshape(
+        hb * rep, bs, hd)
+
+
+# ---------------------------------------------------------------------------
+# Decode variant: one query row per sequence, grid (R*Hkv/hb, n_max)
 # ---------------------------------------------------------------------------
 
 def _dec_mask(j, L, bs, window):
@@ -232,7 +320,7 @@ def _dec_mask(j, L, bs, window):
 
 def _dec_stats_kernel(bt_ref, len_ref, q_ref, k_ref, smax_o, m_o, l_o,
                       smax_ref, m_ref, l_ref,
-                      *, H, bs, n_k, mu, granularity, scale, window):
+                      *, G, n, rep, bs, n_k, mu, granularity, scale, window):
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
@@ -241,12 +329,12 @@ def _dec_stats_kernel(bt_ref, len_ref, q_ref, k_ref, smax_o, m_o, l_o,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    live, ok = _dec_mask(j, len_ref[i // H], bs, window)
+    live, ok = _dec_mask(j, len_ref[i // G], bs, window)
 
     @pl.when(live)
     def _block():
-        q = q_ref[0].astype(jnp.float32) * scale       # (1, hd)
-        k = k_ref[0, 0].astype(jnp.float32)            # (bs, hd)
+        q = q_ref[0].astype(jnp.float32) * scale           # (n, 1, hd)
+        k = _per_query_head(k_ref, rep)                    # (n, bs, hd)
         _stats_block(_y_low(q, k, mu, granularity), ok, smax_ref, m_ref, l_ref)
 
     @pl.when(j == n_k - 1)
@@ -259,7 +347,7 @@ def _dec_stats_kernel(bt_ref, len_ref, q_ref, k_ref, smax_o, m_o, l_o,
 def _dec_kernel(bt_ref, len_ref, tau_ref, q_ref, k_ref, v_ref,
                 smax_in, mlow_in, llow_in, o_ref, nsel_ref,
                 acc_ref, m_ref, l_ref, cnt_ref,
-                *, H, bs, n_k, lamp, mu, granularity, rule, n_ref_ln,
+                *, G, n, rep, bs, n_k, lamp, mu, granularity, rule, n_ref_ln,
                 scale, window):
     i, j = pl.program_id(0), pl.program_id(1)
 
@@ -270,14 +358,14 @@ def _dec_kernel(bt_ref, len_ref, tau_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    L = len_ref[i // H]
+    L = len_ref[i // G]
     live, ok = _dec_mask(j, L, bs, window)
 
     @pl.when(live)
     def _block():
         q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = _per_query_head(k_ref, rep)
+        v = _per_query_head(v_ref, rep)
         y = _lamp_logits(q, k, ok, smax_in[0], mlow_in[0], llow_in[0], L,
                          cnt_ref, lamp=lamp, mu=mu, granularity=granularity,
                          rule=rule, tau=tau_ref[0], n_ref=n_ref_ln)
@@ -316,9 +404,10 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
     _, Hkv, bs, _ = arena_k.shape
     n_max = block_tables.shape[1]
     rep = H // Hkv
+    hb = kv_heads_per_step(Hkv, rep, 1, bs, hd)
+    G, n = Hkv // hb, hb * rep           # head groups per row, heads a step
     scale = hd ** -0.5
     interpret = resolve_interpret(interpret)
-    qf = q.reshape(R * H, 1, hd)
     bt = block_tables.astype(jnp.int32)
     lens = lengths.astype(jnp.int32)
     tau_arr = jnp.asarray(site.tau if tau is None else tau,
@@ -329,66 +418,68 @@ def paged_decode_attention(q, arena_k, arena_v, block_tables, lengths,
     need_stats = lamp and site.rule != "none"
 
     def kv_map(i, j, bt_ref, len_ref, *_):
-        r = i // H
+        r = i // G
         L = len_ref[r]
         hi = (L - 1) // bs
         lo = 0 if window is None else jnp.maximum(L - window, 0) // bs
-        return (bt_ref[r, jnp.clip(j, lo, hi)], (i % H) // rep, 0, 0)
+        return (bt_ref[r, jnp.clip(j, lo, hi)], i % G, 0, 0)
 
-    q_spec = pl.BlockSpec((1, 1, hd), lambda i, j, *_: (i, 0, 0))
-    kv_spec = pl.BlockSpec((1, 1, bs, hd), kv_map)
-    row_spec = pl.BlockSpec((1, 1, 1), lambda i, j, *_: (i, 0, 0))
-    row_shape = jax.ShapeDtypeStruct((R * H, 1, 1), jnp.float32)
+    q_spec = pl.BlockSpec((1, n, 1, hd),
+                          lambda i, j, *_: (i // G, i % G, 0, 0))
+    kv_spec = pl.BlockSpec((1, hb, bs, hd), kv_map)
+    row_spec = pl.BlockSpec((1, n, 1, 1),
+                            lambda i, j, *_: (i // G, i % G, 0, 0))
+    row_shape = jax.ShapeDtypeStruct((R, H, 1, 1), jnp.float32)
 
     if need_stats:
         stats = pl.pallas_call(
-            functools.partial(_dec_stats_kernel, H=H, bs=bs, n_k=n_max,
-                              mu=site.mu, granularity=site.granularity,
-                              scale=scale, window=window),
+            functools.partial(_dec_stats_kernel, G=G, n=n, rep=rep, bs=bs,
+                              n_k=n_max, mu=site.mu,
+                              granularity=site.granularity, scale=scale,
+                              window=window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2,
-                grid=(R * H, n_max),
+                grid=(R * G, n_max),
                 in_specs=[q_spec, kv_spec],
                 out_specs=[row_spec] * 3,
-                scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)] * 3,
+                scratch_shapes=[pltpu.VMEM((n, 1, 1), jnp.float32)] * 3,
             ),
             out_shape=[row_shape] * 3,
             interpret=interpret,
             metadata=LOOKAHEAD,
-        )(bt, lens, qf, arena_k)
+        )(bt, lens, q, arena_k)
     else:
-        stats = [jnp.zeros((R * H, 1, 1), jnp.float32)] * 3
+        stats = [jnp.zeros((R, H, 1, 1), jnp.float32)] * 3
 
     out, nsel = pl.pallas_call(
-        functools.partial(_dec_kernel, H=H, bs=bs, n_k=n_max, lamp=lamp,
-                          mu=site.mu, granularity=site.granularity,
+        functools.partial(_dec_kernel, G=G, n=n, rep=rep, bs=bs, n_k=n_max,
+                          lamp=lamp, mu=site.mu, granularity=site.granularity,
                           rule=site.rule, n_ref_ln=site.n_ref,
                           scale=scale, window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(R * H, n_max),
+            grid=(R * G, n_max),
             in_specs=[q_spec, kv_spec, kv_spec] + [row_spec] * 3,
             out_specs=[q_spec, row_spec],
             scratch_shapes=[
-                pltpu.VMEM((1, hd), jnp.float32),   # acc
-                pltpu.VMEM((1, 1), jnp.float32),    # m
-                pltpu.VMEM((1, 1), jnp.float32),    # l
-                pltpu.VMEM((1, 1), jnp.float32),    # nsel count
+                pltpu.VMEM((n, 1, hd), jnp.float32),   # acc
+                pltpu.VMEM((n, 1, 1), jnp.float32),    # m
+                pltpu.VMEM((n, 1, 1), jnp.float32),    # l
+                pltpu.VMEM((n, 1, 1), jnp.float32),    # nsel count
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((R * H, 1, hd), jnp.float32),
+            jax.ShapeDtypeStruct((R, H, 1, hd), jnp.float32),
             row_shape,
         ],
         interpret=interpret,
         metadata=RECOMPUTE,
-    )(bt, lens, tau_arr, qf, arena_k, arena_v, *stats)
-    return (out.reshape(R, H, 1, hd),
-            jnp.sum(nsel.reshape(R, H), axis=1))
+    )(bt, lens, tau_arr, q, arena_k, arena_v, *stats)
+    return out, jnp.sum(nsel.reshape(R, H), axis=1)
 
 
 # ---------------------------------------------------------------------------
-# Windowed-prefill variant: query tile x block grid (B*H, n_q, n_max)
+# Windowed-prefill variant: query tile x block grid (B*Hkv/hb, n_q, n_max)
 # ---------------------------------------------------------------------------
 
 def _pre_mask(j, q0, qe, bs, wq, window):
@@ -414,7 +505,8 @@ def _pre_mask(j, q0, qe, bs, wq, window):
 
 def _pre_stats_kernel(bt_ref, starts_ref, ql_ref, q_ref, k_ref,
                       smax_o, m_o, l_o, smax_ref, m_ref, l_ref,
-                      *, H, bs, wq, n_k, mu, granularity, scale, window):
+                      *, G, n, rep, bs, wq, n_k, mu, granularity, scale,
+                      window):
     i, t, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
     @pl.when(j == 0)
@@ -423,14 +515,14 @@ def _pre_stats_kernel(bt_ref, starts_ref, ql_ref, q_ref, k_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q0 = starts_ref[i // H] + t * wq
-    qe = jnp.clip(ql_ref[i // H] - t * wq, 0, wq)
+    q0 = starts_ref[i // G] + t * wq
+    qe = jnp.clip(ql_ref[i // G] - t * wq, 0, wq)
     live, ok, _ = _pre_mask(j, q0, qe, bs, wq, window)
 
     @pl.when(live)
     def _block():
-        q = q_ref[0].astype(jnp.float32) * scale       # (wq, hd)
-        k = k_ref[0, 0].astype(jnp.float32)            # (bs, hd)
+        q = q_ref[0].astype(jnp.float32) * scale           # (n, wq, hd)
+        k = _per_query_head(k_ref, rep)                    # (n, bs, hd)
         _stats_block(_y_low(q, k, mu, granularity), ok, smax_ref, m_ref, l_ref)
 
     @pl.when(j == n_k - 1)
@@ -443,7 +535,7 @@ def _pre_stats_kernel(bt_ref, starts_ref, ql_ref, q_ref, k_ref,
 def _pre_kernel(bt_ref, starts_ref, ql_ref, tau_ref, q_ref, k_ref, v_ref,
                 smax_in, mlow_in, llow_in, o_ref, nsel_ref,
                 acc_ref, m_ref, l_ref, cnt_ref,
-                *, H, bs, wq, n_k, lamp, mu, granularity, rule,
+                *, G, n, rep, bs, wq, n_k, lamp, mu, granularity, rule,
                 n_ref_ln, scale, window, Tk):
     i, t, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
 
@@ -454,15 +546,15 @@ def _pre_kernel(bt_ref, starts_ref, ql_ref, tau_ref, q_ref, k_ref, v_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    q0 = starts_ref[i // H] + t * wq
-    qe = jnp.clip(ql_ref[i // H] - t * wq, 0, wq)
+    q0 = starts_ref[i // G] + t * wq
+    qe = jnp.clip(ql_ref[i // G] - t * wq, 0, wq)
     live, ok, qi = _pre_mask(j, q0, qe, bs, wq, window)
 
     @pl.when(live)
     def _block():
         q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
+        k = _per_query_head(k_ref, rep)
+        v = _per_query_head(v_ref, rep)
         # row_lengths as in attention_lamp: clip(qi + 1, 0, window|Tk)
         n_row = jnp.clip(qi[:, :1] + 1, 0, Tk if window is None else window)
         y = _lamp_logits(q, k, ok, smax_in[0], mlow_in[0], llow_in[0], n_row,
@@ -521,8 +613,9 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
     if W % wq:
         raise ValueError(f"W={W} % block_q={wq}")
     n_q = W // wq
+    hb = kv_heads_per_step(Hkv, rep, wq, bs, hd)
+    G, n = Hkv // hb, hb * rep           # head groups per row, heads a step
     Tk = n_max * bs
-    qf = q.reshape(B * H, W, hd)
     bt = block_tables.astype(jnp.int32)
     st = starts.astype(jnp.int32)
     ql = (jnp.full((B,), W, jnp.int32) if qlens is None
@@ -533,7 +626,7 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
     need_stats = lamp and site.rule != "none"   # as in the decode variant
 
     def kv_map(i, t, j, bt_ref, starts_ref, ql_ref, *_):
-        b = i // H
+        b = i // G
         q0 = starts_ref[b] + t * wq
         # clamp the walk to the row's live queries in this tile (>= 1 so a
         # dead tile still resolves to a resident block; pl.when skips it)
@@ -541,58 +634,61 @@ def paged_prefill_attention(q, arena_k, arena_v, block_tables, starts,
         hi = jnp.minimum((q0 + qe - 1) // bs, n_max - 1)
         lo = 0 if window is None else \
             jnp.minimum(jnp.maximum(q0 - window + 1, 0) // bs, hi)
-        return (bt_ref[b, jnp.clip(j, lo, hi)], (i % H) // rep, 0, 0)
+        return (bt_ref[b, jnp.clip(j, lo, hi)], i % G, 0, 0)
 
-    q_spec = pl.BlockSpec((1, wq, hd), lambda i, t, j, *_: (i, t, 0))
-    kv_spec = pl.BlockSpec((1, 1, bs, hd), kv_map)
-    row_spec = pl.BlockSpec((1, wq, 1), lambda i, t, j, *_: (i, t, 0))
-    row_shape = jax.ShapeDtypeStruct((B * H, W, 1), jnp.float32)
+    q_spec = pl.BlockSpec((1, n, wq, hd),
+                          lambda i, t, j, *_: (i // G, i % G, t, 0))
+    kv_spec = pl.BlockSpec((1, hb, bs, hd), kv_map)
+    row_spec = pl.BlockSpec((1, n, wq, 1),
+                            lambda i, t, j, *_: (i // G, i % G, t, 0))
+    row_shape = jax.ShapeDtypeStruct((B, H, W, 1), jnp.float32)
 
     if need_stats:
         stats = pl.pallas_call(
-            functools.partial(_pre_stats_kernel, H=H, bs=bs, wq=wq, n_k=n_max,
-                              mu=site.mu, granularity=site.granularity,
-                              scale=scale, window=window),
+            functools.partial(_pre_stats_kernel, G=G, n=n, rep=rep, bs=bs,
+                              wq=wq, n_k=n_max, mu=site.mu,
+                              granularity=site.granularity, scale=scale,
+                              window=window),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=3,
-                grid=(B * H, n_q, n_max),
+                grid=(B * G, n_q, n_max),
                 in_specs=[q_spec, kv_spec],
                 out_specs=[row_spec] * 3,
-                scratch_shapes=[pltpu.VMEM((wq, 1), jnp.float32)] * 3,
+                scratch_shapes=[pltpu.VMEM((n, wq, 1), jnp.float32)] * 3,
             ),
             out_shape=[row_shape] * 3,
             interpret=interpret,
             metadata=LOOKAHEAD,
-        )(bt, st, ql, qf, arena_k)
+        )(bt, st, ql, q, arena_k)
     else:
-        stats = [jnp.zeros((B * H, W, 1), jnp.float32)] * 3
+        stats = [jnp.zeros((B, H, W, 1), jnp.float32)] * 3
 
     out, nsel = pl.pallas_call(
-        functools.partial(_pre_kernel, H=H, bs=bs, wq=wq, n_k=n_max,
-                          lamp=lamp, mu=site.mu, granularity=site.granularity,
-                          rule=site.rule, n_ref_ln=site.n_ref,
-                          scale=scale, window=window, Tk=Tk),
+        functools.partial(_pre_kernel, G=G, n=n, rep=rep, bs=bs, wq=wq,
+                          n_k=n_max, lamp=lamp, mu=site.mu,
+                          granularity=site.granularity, rule=site.rule,
+                          n_ref_ln=site.n_ref, scale=scale, window=window,
+                          Tk=Tk),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(B * H, n_q, n_max),
+            grid=(B * G, n_q, n_max),
             in_specs=[q_spec, kv_spec, kv_spec] + [row_spec] * 3,
             out_specs=[q_spec, row_spec],
             scratch_shapes=[
-                pltpu.VMEM((wq, hd), jnp.float32),  # acc
-                pltpu.VMEM((wq, 1), jnp.float32),   # m
-                pltpu.VMEM((wq, 1), jnp.float32),   # l
-                pltpu.VMEM((wq, 1), jnp.float32),   # nsel counts
+                pltpu.VMEM((n, wq, hd), jnp.float32),  # acc
+                pltpu.VMEM((n, wq, 1), jnp.float32),   # m
+                pltpu.VMEM((n, wq, 1), jnp.float32),   # l
+                pltpu.VMEM((n, wq, 1), jnp.float32),   # nsel counts
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((B * H, W, hd), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, W, hd), jnp.float32),
             row_shape,
         ],
         interpret=interpret,
         metadata=RECOMPUTE,
-    )(bt, st, ql, tau_arr, qf, arena_k, arena_v, *stats)
-    return (out.reshape(B, H, W, hd),
-            jnp.sum(nsel.reshape(B, H, W), axis=1))
+    )(bt, st, ql, tau_arr, q, arena_k, arena_v, *stats)
+    return out, jnp.sum(nsel[..., 0], axis=1)
 
 
 def paged_mixed_attention(q, arena_k, arena_v, block_tables, starts, qlens,
@@ -640,7 +736,9 @@ def grid_blocks(starts, qlens, rows: int, Bb: int, n_max: int,
                 width: Optional[int] = None) -> Tuple[int, int]:
     """(live, walked): the KV grid steps of one pass of
     ``paged_prefill_attention`` (one head, one layer) that touch a live
-    block, and all the grid steps it walks.
+    block, and all the grid steps it walks. Every head group walks the same
+    steps, so the kernel's own grid is this one times Hkv / hb, and the
+    live share does not depend on the fold.
 
     The grid is (Bb rows, tiles, n_max blocks); a step is live when its
     block index lies in the [lo, hi] range ``kv_map`` clamps to, for a tile

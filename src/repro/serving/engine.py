@@ -46,12 +46,12 @@ launch span -- mixed, prefill, decode, draft, verify, each holding `inputs`
 puts -- then sync (`wait` for the device, `readback` of the outputs),
 account (LAMP counts and per-row bookkeeping), emit and policy; `mixed`
 carries rows, bucket, tokens and, while recording, the kernel grid's
-kv_live / kv_walked. The
-engine's counters live in the obs metrics registry (`stats()` is a view over
-it; the legacy attribute names are properties over the same counters). LAMP
-recompute counts are threaded per layer: the jitted steps return (L, B)
-selected/valid counts, accumulated into per-layer counters, a bounded
-recompute-rate time series, and per-request per-layer breakdowns. Jit
+kv_live / kv_walked and kv_heads_per_step (the KV heads one grid step
+folds). The engine's counters live in the obs metrics registry (`stats()`
+is a view over it; the legacy attribute names are properties over the same
+counters). LAMP recompute counts are threaded per layer: the jitted steps
+return (L, B) selected/valid counts, accumulated into per-layer counters, a
+bounded recompute-rate time series, and per-request per-layer breakdowns. Jit
 compiles are detected per call (the bucketed step cache growing) and logged
 with their bucket shape and wall time -- recompile storms are the canonical
 silent perf killer of fixed-shape serving.
@@ -69,7 +69,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.paged_attention import grid_blocks
+from repro.kernels.paged_attention import grid_blocks, kv_heads_per_step
 from repro.models import transformer
 from repro.obs import ObsConfig, Observability
 from repro.obs.audit import AuditConfig, ShadowAuditor
@@ -1504,6 +1504,9 @@ class LampEngine:
             mixed_args["kv_live"], mixed_args["kv_walked"] = grid_blocks(
                 starts, qlens, len(seqs), Bb, self.blocks_per_seq,
                 self.econfig.block_size, window=self.cfg.window)
+            mixed_args["kv_heads_per_step"] = kv_heads_per_step(
+                self.cfg.n_kv_heads, self.cfg.n_heads // self.cfg.n_kv_heads,
+                Wb, self.econfig.block_size, self.cfg.hd)
         emit = n_acc = None
         if not spec_round:
             mixed_fn, _ = self._step_fns(seqs)

@@ -16,6 +16,7 @@ import pytest
 import jax
 
 from repro.configs import get_config, reduced as reduce_cfg
+from repro.kernels.paged_attention import kv_heads_per_step
 from repro.models import api
 from repro.obs import ObsConfig, Observability
 from repro.obs.metrics import Counter, Gauge, Histogram, MetricsRegistry
@@ -470,7 +471,7 @@ def test_engine_trace_on_token_identity_and_stats_compat(model):
 def test_engine_phase_spans_on_the_profiler(model, fake_annotation):
     """Every engine phase opens an `engine.*` annotation inside the step's
     root span; while a profiler records, the fused launch span carries its
-    rows, bucket, tokens and kernel grid counts."""
+    rows, bucket, tokens, kernel grid counts and head fold."""
     fake_annotation.enabled = True
     cfg, params = model
     eng, _ = _run(cfg, params)
@@ -499,6 +500,9 @@ def test_engine_phase_spans_on_the_profiler(model, fake_annotation):
         assert a["rows"] <= rows and a["tokens"] <= rows * width
         assert 0 < a["kv_live"] <= a["kv_walked"]
         assert a["kv_walked"] == rows * eng.blocks_per_seq
+        assert a["kv_heads_per_step"] == kv_heads_per_step(
+            cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, width,
+            eng.econfig.block_size, cfg.hd)
 
 
 def test_engine_phase_extents_fake_clock(model, fake_annotation):

@@ -30,8 +30,9 @@ from repro.core.attention import (attention_lamp, attention_reference,
                                   decode_attention_lamp)
 from repro.core.policy import LampSite
 from repro.kernels import ops
-from repro.kernels.paged_attention import (decode_kv_bytes, grid_blocks,
-                                           supports_site)
+from repro.kernels.paged_attention import (VMEM_BUDGET, decode_kv_bytes,
+                                           grid_blocks, grid_step_vmem_bytes,
+                                           kv_heads_per_step, supports_site)
 from repro.models.layers import require_kernel_site
 
 H, HKV, HD = 4, 2, 16
@@ -83,7 +84,7 @@ def _grid(x):
     return np.clip(np.round(x * 64) / 64, -8, 8)
 
 
-def make_paged(seed, lengths, bs, n_max, *, span=None):
+def make_paged(seed, lengths, bs, n_max, *, span=None, hkv=HKV):
     """Random head-major arena (n_blocks, Hkv, bs, hd) + per-row block
     tables. Row r owns ceil(span[r]/bs) distinct shuffled blocks (block 0
     stays the null block); the rest of the table is null-padded. span
@@ -92,9 +93,9 @@ def make_paged(seed, lengths, bs, n_max, *, span=None):
     R = len(lengths)
     span = list(lengths) if span is None else list(span)
     n_blocks = 1 + R * n_max
-    arena_k = jnp.asarray(_grid(rng.normal(size=(n_blocks, HKV, bs, HD)) * 1.5),
+    arena_k = jnp.asarray(_grid(rng.normal(size=(n_blocks, hkv, bs, HD)) * 1.5),
                           jnp.float32)
-    arena_v = jnp.asarray(_grid(rng.normal(size=(n_blocks, HKV, bs, HD))),
+    arena_v = jnp.asarray(_grid(rng.normal(size=(n_blocks, hkv, bs, HD))),
                           jnp.float32)
     perm = rng.permutation(np.arange(1, n_blocks))
     bt = np.zeros((R, n_max), np.int32)
@@ -104,29 +105,31 @@ def make_paged(seed, lengths, bs, n_max, *, span=None):
     return arena_k, arena_v, jnp.asarray(bt)
 
 
-def gathered_heads(arena_k, arena_v, bt):
+def gathered_heads(arena_k, arena_v, bt, rep=H // HKV):
     """(R, H, n_max * bs, hd) per-row views: block walk concatenated along
     the position axis of each KV head, then repeated for GQA."""
     def view(arena):
         blocks = arena[bt]                          # (R, n_max, Hkv, bs, hd)
         flat = jnp.concatenate([blocks[:, j] for j in range(bt.shape[1])],
                                axis=2)              # (R, Hkv, n_max*bs, hd)
-        return _repeat_kv(flat, H // HKV)
+        return _repeat_kv(flat, rep)
     return view(arena_k), view(arena_v)
 
 
 def check_decode(seed, lengths, bs, n_max, site, *, window=None,
-                 check_dense=False):
-    """Fused decode vs gather (vs dense) on one random paged layout."""
-    arena_k, arena_v, bt = make_paged(seed, lengths, bs, n_max)
+                 check_dense=False, heads=(H, HKV)):
+    """Fused decode vs gather (vs dense) on one random paged layout with
+    `heads` = (query heads, KV heads)."""
+    nh, hkv = heads
+    arena_k, arena_v, bt = make_paged(seed, lengths, bs, n_max, hkv=hkv)
     lengths = jnp.asarray(lengths, jnp.int32)
     rng = np.random.default_rng(seed + 7)
-    q = jnp.asarray(_grid(rng.normal(size=(len(lengths), H, 1, HD)) * 1.5),
+    q = jnp.asarray(_grid(rng.normal(size=(len(lengths), nh, 1, HD)) * 1.5),
                     jnp.float32)
 
     out, nsel = ops.paged_decode_attention(q, arena_k, arena_v, bt, lengths,
                                            site, window=window)
-    kh, vh = gathered_heads(arena_k, arena_v, bt)
+    kh, vh = gathered_heads(arena_k, arena_v, bt, nh // hkv)
     want, aux = decode_attention_lamp(q, kh, vh, lengths, site,
                                       window=window, reduce=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), **TOL)
@@ -143,19 +146,22 @@ def check_decode(seed, lengths, bs, n_max, site, *, window=None,
 
 
 def check_prefill(seed, starts, bs, n_max, site, *, W=8, window=None,
-                  block_q=None):
-    """Fused windowed prefill vs gather attention_lamp at offsets=starts."""
+                  block_q=None, heads=(H, HKV)):
+    """Fused windowed prefill vs gather attention_lamp at offsets=starts,
+    with `heads` = (query heads, KV heads)."""
+    nh, hkv = heads
     starts = list(starts)
     span = [s + W for s in starts]
-    arena_k, arena_v, bt = make_paged(seed, span, bs, n_max, span=span)
+    arena_k, arena_v, bt = make_paged(seed, span, bs, n_max, span=span,
+                                      hkv=hkv)
     st = jnp.asarray(starts, jnp.int32)
     rng = np.random.default_rng(seed + 13)
-    q = jnp.asarray(_grid(rng.normal(size=(len(starts), H, W, HD)) * 1.5),
+    q = jnp.asarray(_grid(rng.normal(size=(len(starts), nh, W, HD)) * 1.5),
                     jnp.float32)
 
     out, nsel = ops.paged_prefill_attention(q, arena_k, arena_v, bt, st, site,
                                             window=window, block_q=block_q)
-    kh, vh = gathered_heads(arena_k, arena_v, bt)
+    kh, vh = gathered_heads(arena_k, arena_v, bt, nh // hkv)
     if site.enabled:
         want, aux = attention_lamp(q, kh, vh, site, causal=True,
                                    window=window, offset=st, reduce=False)
@@ -198,6 +204,61 @@ def test_decode_sliding_window(site_name):
 def test_prefill_sliding_window(site_name):
     check_prefill(3, [0, 9, 17], 8, 4, SITES[site_name], W=8, window=12,
                   block_q=4)
+
+
+# ------------------------------------------------------------- head fold
+# A grid step walks one KV block for hb KV heads, and hb follows the shape
+# alone (kv_heads_per_step), so each case takes a shape whose rule gives the
+# fold it covers: every head, an intermediate divisor, or one head.
+
+PREFILL_FOLDS = {
+    # name: ((H, Hkv), starts, bs, n_max, W, window, site, hb)
+    "all-gqa": ((8, 4), [0, 5, 21], 8, 4, 8, None, "strict-g1", 4),
+    "mid-window": ((4, 4), [0, 37], 16, 35, 512, 100, "relaxed-g0", 2),
+    "one": ((2, 2), [3], 16, 65, 1024, None, "ln-g0", 1),
+}
+
+DECODE_FOLDS = {
+    # name: ((H, Hkv), lengths, bs, n_max, window, site, hb)
+    "all-gqa": ((8, 4), [5, 16, 27], 8, 4, None, "relaxed-g1", 4),
+    "mid": ((143, 143), [3, 17, 30], 8, 4, None, "relaxed-g0", 13),
+    "one-window": ((131, 131), [5, 16, 27], 8, 4, 12, "relaxed-g0", 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREFILL_FOLDS))
+def test_prefill_head_fold(case):
+    heads, starts, bs, n_max, W, window, site, hb = PREFILL_FOLDS[case]
+    assert kv_heads_per_step(heads[1], heads[0] // heads[1], W, bs, HD) == hb
+    check_prefill(11, starts, bs, n_max, SITES[site], W=W, window=window,
+                  heads=heads)
+
+
+@pytest.mark.parametrize("case", sorted(DECODE_FOLDS))
+def test_decode_head_fold(case):
+    heads, lengths, bs, n_max, window, site, hb = DECODE_FOLDS[case]
+    assert kv_heads_per_step(heads[1], heads[0] // heads[1], 1, bs, HD) == hb
+    check_decode(12, lengths, bs, n_max, SITES[site], window=window,
+                 heads=heads)
+
+
+@pytest.mark.parametrize("n_heads", [12, 25])
+def test_kv_heads_per_step_rule(n_heads):
+    """GPT-2's fold (one query head per KV head, 16-token blocks, 64-wide
+    heads): every head at a decode tile; at every width a divisor of the
+    heads within the VMEM budget, with no larger divisor fitting; and the
+    widest prefill tile within the budget."""
+    assert kv_heads_per_step(n_heads, 1, 1, 16, 64) == n_heads
+    divisors = [d for d in range(1, n_heads + 1) if n_heads % d == 0]
+    for wq in (1, 8, 16, 32, 64, 128, 256, 512, 1024):
+        hb = kv_heads_per_step(n_heads, 1, wq, 16, 64)
+        assert hb in divisors
+        assert hb == 1 or grid_step_vmem_bytes(hb, 1, wq, 16, 64) \
+            <= VMEM_BUDGET
+        assert all(grid_step_vmem_bytes(d, 1, wq, 16, 64) > VMEM_BUDGET
+                   for d in divisors if d > hb)
+    hb = kv_heads_per_step(n_heads, 1, 1024, 16, 64)
+    assert grid_step_vmem_bytes(hb, 1, 1024, 16, 64) <= VMEM_BUDGET
 
 
 # --------------------------------------------------- mask/boundary corners

@@ -126,6 +126,38 @@ def test_lamp_passes_tagged_for_v5e(one_chip, kernel):
     assert all(n.startswith(f"%paged_{kernel}_attention.") for n, _ in ops)
 
 
+# The buckets the benchmark's cells run, (heads, rows, width): GPT-2 XL
+# decodes 4 rows and prefills up to 1024 tokens; GPT-2 small decodes 64 rows
+# and prefills 512-wide buckets of 8 rows. Width 1 folds every head into a
+# grid step; the widest buckets fold fewer (kv_heads_per_step), and the
+# compile refuses a fold whose tiles overrun the VMEM Mosaic grants.
+CELL_BUCKETS = {
+    "xl-4x1": (25, 4, 1),
+    "xl-4x1024": (25, 4, 1024),
+    "small-64x1": (12, 64, 1),
+    "small-8x512": (12, 8, 512),
+}
+
+
+@pytest.mark.parametrize("bucket", sorted(CELL_BUCKETS))
+def test_paged_mixed_compiles_at_cell_buckets_for_v5e(one_chip, bucket):
+    """The mixed-row kernel pair at each cell bucket, on the deployment
+    tier the cells run: the Pallas kernel is in the program, and both LAMP
+    passes keep their `lamp_pass` tags."""
+    heads, B, W = CELL_BUCKETS[bucket]
+    site = TIERS["deploy-relaxed-g0"]
+    arena = _spec(one_chip, (N_BLOCKS, heads, BS, HD))
+    rows = _spec(one_chip, (B,), jnp.int32)
+    text = jax.jit(lambda q, k, v, bt, st, ql: paged_prefill_attention(
+        q, k, v, bt, st, site, qlens=ql, interpret=False)).lower(
+        _spec(one_chip, (B, heads, W, HD)), arena, arena,
+        _spec(one_chip, (B, N_MAX), jnp.int32), rows, rows).compile().as_text()
+    assert "tpu_custom_call" in text
+    passes = re.findall(r"%paged_prefill_attention\.\d+ = .*?kernel_metadata="
+                        r"\{\s*\"lamp_pass\":\"(\w+)\"", text)
+    assert sorted(passes) == ["lookahead", "recompute"]
+
+
 def test_fused_mixed_step_compiles_for_v5e(one_chip, monkeypatch):
     """The engine's fused mixed step (gpt2, LAMP on, kernel="pallas") for an
     8-row, 16-wide bucket. The engine's kernels resolve interpret mode from
